@@ -30,8 +30,8 @@ Two scenarios, matching the two halves of the paper's search:
 Also kernel-vs-oracle microbenches (interpret mode measures the correctness
 path; on TPU the Pallas kernels replace the XLA fallbacks), and a
 ``sharded`` scenario: the same quota-bounded search run device-parallel at
-2/4/8 forced host devices (``--xla_force_host_platform_device_count``, in a
-subprocess so this process keeps its device view), parity-checked bit-exact
+2/4/8 devices, as many as this process sees (CI forces 8 host devices with
+``--xla_force_host_platform_device_count``), parity-checked bit-exact
 against the single-device engine. On a CPU host the shards share the same
 cores, so this tracks collective overhead, not a real speedup — the
 trajectory artifact is what CI gates on.
@@ -74,11 +74,6 @@ executed as a script) — the machine-readable perf trajectory artifact.
 from __future__ import annotations
 
 import functools
-import json
-import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -87,7 +82,7 @@ import numpy as np
 
 from benchmarks.common import Setup, emit, write_bench_json
 from repro.core import _legacy_beam, beam, distances, metrics
-from repro.core.beam import batched_greedy_search
+from repro.core.beam import batched_greedy_search, sharded_greedy_search
 from repro.kernels import ops
 
 BATCH_SIZES = (1, 8, 32, 64, 128)
@@ -96,7 +91,7 @@ K = 10
 QUOTA = 128  # stage-2 scenario budget
 E_QUOTA = 2  # wave width under a quota (recall-safe)
 E_UNBOUNDED = 6  # wave width for convergence-bounded search
-SHARD_COUNTS = (2, 4, 8)  # forced host devices for the sharded scenario
+SHARD_COUNTS = (2, 4, 8)  # shard counts for the sharded scenario
 SHARD_BATCH = 32
 # dedup-backend scenario: a corpus big enough that the (B, N) bitmap's
 # round-trips through the host-driven dispatches dominate the fixed
@@ -193,76 +188,45 @@ def _scenario(name, setup, em, queries, true_ids, *, quota, expand_width,
     return {"expand_width": expand_width, "quota": quota, "batches": batches}
 
 
-_SHARDED_PROG = """
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=" + sys.argv[2])
-sys.path.insert(0, "src")
-import numpy as np
-import jax, jax.numpy as jnp
-from repro.core.beam import sharded_greedy_search
-
-data = np.load(sys.argv[1])
-emb, adj = jnp.asarray(data["emb"]), jnp.asarray(data["adj"])
-qs, entries = jnp.asarray(data["qs"]), jnp.asarray(data["entries"])
-quota, beam, e = int(data["quota"]), int(data["beam"]), int(data["e"])
-
-def timed(shards):
-    f = lambda q: sharded_greedy_search(
-        emb, adj, q, entries, shards=shards, metric="l2", beam_width=beam,
-        pool_size=beam, quota=quota, expand_width=e, max_steps=4 * quota)
-    r = jax.block_until_ready(f(qs))  # compile
-    best = float("inf")
-    for _ in range(5):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(qs))
-        best = min(best, time.perf_counter() - t0)
-    return best, r
-
-base_wall, base = timed(1)
-out = {"devices": int(sys.argv[2]), "unsharded_us_per_query":
-       base_wall / qs.shape[0] * 1e6, "shards": {}}
-for s in (int(x) for x in sys.argv[3].split(",")):
-    wall, r = timed(s)
-    parity = all(
-        np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(base, r))
-    assert parity, f"sharded engine diverged at shards={s}"
-    out["shards"][str(s)] = {
-        "us_per_query": wall / qs.shape[0] * 1e6,
-        "speedup_vs_unsharded": base_wall / wall,
-        "parity_bit_exact": parity,
-    }
-print("RESULT_JSON=" + json.dumps(out))
-"""
-
-
 def _sharded_scenario(setup, em, queries) -> dict:
-    """Device-parallel engine at 2/4/8 forced host devices (subprocess)."""
+    """Device-parallel engine over the devices this process sees.
+
+    Runs in this process (a chip belongs to one process), at every shard
+    count of ``SHARD_COUNTS`` the device count allows, parity-checked
+    bit-exact against the single-device engine. CI gives it 8 forced host
+    devices through ``XLA_FLAGS`` (``scripts/ci.sh --bench``).
+    """
     b = SHARD_BATCH
+    qs = queries[:b]
     entries = jnp.broadcast_to(
         jnp.array([setup.index_d.medoid], jnp.int32), (b, 1))
-    ndev = max(SHARD_COUNTS)
-    with tempfile.TemporaryDirectory() as td:
-        path = os.path.join(td, "sharded_bench.npz")
-        np.savez(path, emb=np.asarray(em.embeddings),
-                 adj=np.asarray(setup.index_d.adjacency),
-                 qs=np.asarray(queries[:b]), entries=np.asarray(entries),
-                 quota=QUOTA, beam=BEAM, e=E_QUOTA)
-        res = subprocess.run(
-            [sys.executable, "-c", _SHARDED_PROG, path, str(ndev),
-             ",".join(str(s) for s in SHARD_COUNTS)],
-            capture_output=True, text=True, timeout=1800,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if res.returncode != 0:
-        raise RuntimeError(f"sharded scenario failed: {res.stderr[-2000:]}")
-    line = next(ln for ln in res.stdout.splitlines()
-                if ln.startswith("RESULT_JSON="))
-    out = json.loads(line[len("RESULT_JSON="):])
-    for s, row in sorted(out["shards"].items(), key=lambda kv: int(kv[0])):
-        emit(f"perf/sharded_s{s}_b{b}", row["us_per_query"],
-             f"us_per_query;x_vs_unsharded={row['speedup_vs_unsharded']:.2f}"
-             f";parity={row['parity_bit_exact']}")
+
+    def search(shards):
+        return lambda q: sharded_greedy_search(
+            em.embeddings, setup.index_d.adjacency, q, entries,
+            shards=shards, metric="l2", beam_width=BEAM, pool_size=BEAM,
+            quota=QUOTA, expand_width=E_QUOTA, max_steps=4 * QUOTA)
+
+    base_fn = search(1)
+    base_wall = _time(base_fn, qs, reps=5)
+    base = base_fn(qs)
+    out = {"devices": len(jax.devices()),
+           "unsharded_us_per_query": base_wall / b * 1e6, "shards": {}}
+    for s in (s for s in SHARD_COUNTS if s <= out["devices"]):
+        fn = search(s)
+        wall = _time(fn, qs, reps=5)
+        parity = all(np.array_equal(np.asarray(x), np.asarray(y))
+                     for x, y in zip(base, fn(qs)))
+        if not parity:
+            raise AssertionError(f"sharded engine diverged at shards={s}")
+        out["shards"][str(s)] = {
+            "us_per_query": wall / b * 1e6,
+            "speedup_vs_unsharded": base_wall / wall,
+            "parity_bit_exact": parity,
+        }
+        emit(f"perf/sharded_s{s}_b{b}", wall / b * 1e6,
+             f"us_per_query;x_vs_unsharded={base_wall / wall:.2f}"
+             f";parity={parity}")
     return out
 
 
@@ -399,10 +363,9 @@ def _matmul_scenario() -> dict:
       backends) on random waves, recorded honestly: on this CPU host XLA
       fuses the row gather *into* the reduce loop (one pass, no (B, K, D)
       temp), while ``dot_general`` forces the gathered operand to
-      materialize — so the full op is a memory-bandwidth wash here. On
-      TPU the Pallas tile streams rows HBM→VMEM by prefetched id either
-      way, which is where the full-op win lands; the trajectory artifact
-      records both so that shift is visible when accelerator CI exists.
+      materialize — so the full op is a memory-bandwidth wash here. The
+      Pallas tile reads an XLA-gathered block too; on the chip neither
+      form is timed yet, and the trajectory artifact records both.
     """
     rng = np.random.default_rng(7)
     corpus = jnp.asarray(

@@ -30,6 +30,7 @@ def main() -> None:
                             bench_search_perf, bench_seeding,
                             bench_serve_async, bench_serve_faults,
                             bench_table1, common)
+    from repro.launch.compile_cache import use_compile_cache
 
     benches = [
         ("table1", "table1", bench_table1.run),
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, metavar="SLUG[,SLUG...]",
                     help="run only the benchmarks with these artifact slugs")
     args = ap.parse_args()
+    use_compile_cache()
     if args.only:
         wanted = set(args.only.split(","))
         unknown = wanted - {slug for _, slug, _ in benches}

@@ -57,7 +57,10 @@ if [[ "${1:-}" == "--bench" ]]; then
   trap 'rm -rf "$out"' EXIT
   slugs="$(python scripts/check_bench.py --manifest benchmarks/gates.json \
     --list-slugs)"
-  BENCH_OUT_DIR="$out" PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
+  # 8 forced host devices: search_perf's sharded scenario runs in-process
+  # over the devices it sees
+  XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \
+    BENCH_OUT_DIR="$out" PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python benchmarks/run.py --only "$slugs"
   python scripts/check_bench.py --manifest benchmarks/gates.json \
     --baseline-dir . --new-dir "$out" "$@"

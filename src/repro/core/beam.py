@@ -949,7 +949,6 @@ def sharded_greedy_search(
     from repro.distributed import collectives
     from repro.distributed.sharding import (SEARCH_AXIS, search_mesh,
                                             shard_corpus, shard_corpus_view)
-    from repro.launch.mesh import shard_map
 
     n_points = kernel_backend.corpus_rows(corpus).shape[0]
     be = kernel_backend.resolve_backend(
@@ -1029,9 +1028,10 @@ def sharded_greedy_search(
     # bitmap: local column slices -> global (B, S*nl); sorted: the program
     # materializes the replicated (B, N) bitmap from the replicated set
     scored_spec = _P(None, axis) if dedup == "bitmap" else rep2
-    res = shard_map(
+    res = jax.shard_map(
         program,
         mesh=mesh,
+        check_vma=False,
         in_specs=(_P(axis, None, None), _P(axis, None), _P(axis, None),
                   _P(axis, None), _P(axis, None),
                   rep2, rep2, rep2, rep1, rep1, rep1),
@@ -1127,8 +1127,6 @@ class ShardedStepper:
         column-sharded (bitmap) or replicated (sorted). ``dedup`` must be
         concrete here — the engine resolves "auto" and quantizes
         ``set_capacity`` before calling."""
-        from repro.launch.mesh import shard_map
-
         rep2, rep1, state_spec = self._specs(dedup)
 
         def build():
@@ -1138,8 +1136,8 @@ class ShardedStepper:
                     quota=q, shard=self.ctx, dedup=dedup,
                     set_capacity=set_capacity)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh, in_specs=(rep2, rep1),
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False, in_specs=(rep2, rep1),
                 out_specs=(state_spec, rep2, rep2)))
 
         return self._program(("init", pool_size, dedup, set_capacity),
@@ -1163,8 +1161,6 @@ class ShardedStepper:
         cover-tree descent's program shape."""
         from jax.sharding import PartitionSpec as _P
 
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         rep2, rep1, state_spec = self._specs(dedup)
         if expand_cap is None:
@@ -1184,8 +1180,8 @@ class ShardedStepper:
                         expand_width=ew, expand_cap=cap, shard=self.ctx,
                         level=lev, wave_dedup=wave_dedup)
 
-                return jax.jit(shard_map(
-                    f, mesh=self.mesh,
+                return jax.jit(jax.shard_map(
+                    f, mesh=self.mesh, check_vma=False,
                     in_specs=(state_spec, adj_spec, rep1, rep1, rep1, rep1,
                               rep1),
                     out_specs=(state_spec, rep2, rep2, rep1)))
@@ -1196,8 +1192,8 @@ class ShardedStepper:
                     expand_width=ew, expand_cap=cap, shard=self.ctx,
                     wave_dedup=wave_dedup)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
                 in_specs=(state_spec, adj_spec, rep1, rep1, rep1, rep1),
                 out_specs=(state_spec, rep2, rep2, rep1)))
 
@@ -1215,8 +1211,6 @@ class ShardedStepper:
                rows: Array) -> BatchedSearchState:
         """Sharded :func:`reset_expanded` — re-open the masked rows'
         frontiers between cover-tree levels (pools and dedup untouched)."""
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         _, rep1, state_spec = self._specs(dedup)
 
@@ -1224,9 +1218,9 @@ class ShardedStepper:
             def f(s, r):
                 return reset_expanded(s, r)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh, in_specs=(state_spec, rep1),
-                out_specs=state_spec))
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
+                in_specs=(state_spec, rep1), out_specs=state_spec))
 
         b = state.pool_ids.shape[0]
         return self._program(("reopen", dedup), build)(
@@ -1235,8 +1229,6 @@ class ShardedStepper:
     def commit(self, state: BatchedSearchState, safe: Array, keep: Array,
                dists: Array) -> BatchedSearchState:
         """Sharded :func:`commit_scores` (replicated merge, dedup untouched)."""
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         rep2, _, state_spec = self._specs(dedup)
         be = self.backend
@@ -1245,8 +1237,8 @@ class ShardedStepper:
             def f(s, sf, kp, d):
                 return commit_scores(s, sf, kp, d, backend=be)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
                 in_specs=(state_spec, rep2, rep2, rep2),
                 out_specs=state_spec))
 
@@ -1260,8 +1252,6 @@ class ShardedStepper:
         resident state for newly admitted queries (the slot pool's
         admission step). Non-reset rows pass through bit-exactly; the
         returned entry wave commits as a no-op on them."""
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         rep2, rep1, state_spec = self._specs(dedup)
 
@@ -1269,8 +1259,8 @@ class ShardedStepper:
             def f(s, rs, entries, q):
                 return reset_slots(s, rs, entries, q, shard=self.ctx)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
                 in_specs=(state_spec, rep1, rep2, rep1),
                 out_specs=(state_spec, rep2, rep2)))
 
@@ -1283,8 +1273,6 @@ class ShardedStepper:
                beam_width: Array, max_steps: Array) -> Array:
         """Replicated per-row :func:`active_mask` — the slot pool reads it
         every step to detect finished slots (occupied & ~active)."""
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         _, rep1, state_spec = self._specs(dedup)
 
@@ -1292,8 +1280,8 @@ class ShardedStepper:
             def f(s, q, bw, ms):
                 return active_mask(s, beam_width=bw, quota=q, max_steps=ms)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
                 in_specs=(state_spec, rep1, rep1, rep1), out_specs=rep1))
 
         b = state.pool_ids.shape[0]
@@ -1306,8 +1294,6 @@ class ShardedStepper:
         """Replicated ``active_mask(...).any()`` — the host loop condition."""
         from jax.sharding import PartitionSpec as _P
 
-        from repro.launch.mesh import shard_map
-
         dedup = self._dedup_of(state)
         _, rep1, state_spec = self._specs(dedup)
 
@@ -1316,8 +1302,8 @@ class ShardedStepper:
                 return active_mask(
                     s, beam_width=bw, quota=q, max_steps=ms).any()
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False,
                 in_specs=(state_spec, rep1, rep1, rep1), out_specs=_P()))
 
         b = state.pool_ids.shape[0]
@@ -1331,7 +1317,6 @@ class ShardedStepper:
         lost). Sorted backend: the replicated set's unique count — the
         replication invariant (every device computes the same answer)."""
         from repro.distributed import collectives
-        from repro.launch.mesh import shard_map
 
         dedup = self._dedup_of(state)
         _, rep1, state_spec = self._specs(dedup)
@@ -1344,8 +1329,9 @@ class ShardedStepper:
                 return collectives.bitmap_count(
                     s.scored, axis_name=self.axis_name)
 
-            return jax.jit(shard_map(
-                f, mesh=self.mesh, in_specs=(state_spec,), out_specs=rep1))
+            return jax.jit(jax.shard_map(
+                f, mesh=self.mesh, check_vma=False, in_specs=(state_spec,),
+                out_specs=rep1))
 
         return self._program(("count", dedup), build)(state)
 

@@ -130,12 +130,11 @@ def sharded_bimetric_search(
         calls = jax.lax.psum(n_calls, model_axis)
         return top_ids, top_dd, calls
 
-    from repro.launch.mesh import shard_map
-
     qspec = P(data_axes if len(data_axes) > 1 else data_axes[0], None)
-    out = shard_map(
+    out = jax.shard_map(
         program,
         mesh=mesh,
+        check_vma=False,
         in_specs=(P(model_axis), P(model_axis), P(model_axis), P(model_axis), qspec, qspec),
         out_specs=(qspec, qspec, P(data_axes if len(data_axes) > 1 else data_axes[0])),
     )(index.adjacency, index.medoid, index.emb_cheap, index.emb_expensive,

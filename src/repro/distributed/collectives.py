@@ -53,7 +53,6 @@ from jax import lax
 
 from repro.kernels import backend as kernel_backend
 from repro.kernels import ops
-from repro.launch.mesh import axis_size
 
 Array = jax.Array
 
@@ -201,7 +200,7 @@ def allgather_matmul(x: Array, w: Array, axis_name: str) -> Array:
     rotating shards around the ring and filling the output block that each
     incoming shard corresponds to. One send/recv overlaps one block matmul.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m_local = x.shape[0]
     out = jnp.zeros((m_local * n_dev, w.shape[1]), w.dtype)
@@ -225,7 +224,7 @@ def matmul_reducescatter(x: Array, w: Array, axis_name: str) -> Array:
     of a row-sharded K×N. Returns the (m/n_dev, n) reduce-scattered product of
     the full x @ w, accumulating partial sums as they travel the ring.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = x.shape[0]
     assert m % n_dev == 0, "row count must divide the axis size"

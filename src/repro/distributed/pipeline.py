@@ -36,8 +36,7 @@ def gpipe_apply(
     the pipeline axis; only stage 0 consumes them. Returns (n_micro, mb, ...)
     outputs, replicated across stages after a final masked psum.
     """
-    from repro.launch.mesh import axis_size
-    n_stages = axis_size(axis_name)  # static python int inside shard_map
+    n_stages = lax.axis_size(axis_name)  # static python int inside shard_map
     s_idx = lax.axis_index(axis_name)
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
     ring = [(i, (i + 1) % n_stages) for i in range(n_stages)]

@@ -104,16 +104,14 @@ def shard_corpus_view(corpus, n_shards: int, *, quantize: str | None = None):
 
 def search_mesh(n_shards: int, axis_name: str = SEARCH_AXIS) -> Mesh:
     """1-D mesh over the first ``n_shards`` local devices."""
-    from repro.launch.mesh import axis_types_kw
-
     devices = jax.devices()
     if len(devices) < n_shards:
         raise ValueError(
-            f"shards={n_shards} needs {n_shards} devices, have {len(devices)}"
-            " (force host devices with"
-            " XLA_FLAGS=--xla_force_host_platform_device_count=K)")
+            f"shards={n_shards} needs {n_shards} devices, but this process "
+            f"sees {len(devices)} {devices[0].platform} device(s)")
     return jax.make_mesh((n_shards,), (axis_name,),
-                         devices=devices[:n_shards], **axis_types_kw(1))
+                         devices=devices[:n_shards],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 # ZeRO stage for LM params: 3 = params FSDP+TP sharded (default);
 # 1 = params TP-only (replicated over data; optimizer state stays FSDP

@@ -44,8 +44,8 @@ Backend-selection contract
   then a **lossy proxy**: norms are computed over the dequantized rows,
   and every backend scores exactly that proxy through one dequant
   semantics (``ref.dequant_rows_ref``) — the Pallas tile dequantizes
-  in-register (scale/zero-point ride the prefetched row-metadata operand
-  next to the norms), ``xla_matmul`` runs a dequant-then-dot epilogue,
+  in VMEM (scale/zero-point ride the row-metadata operand next to the
+  norms), ``xla_matmul`` runs a dequant-then-dot epilogue,
   and ``"ref"`` dispatches the quantized oracles
   (``ref.gather_score_quant_ref``). This is the bi-metric paper's own
   contract: the cheap stage may be lossy (quantization error folds into

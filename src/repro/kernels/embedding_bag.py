@@ -14,9 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams; local alias, no namespace mutation
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
@@ -61,7 +58,7 @@ def embedding_bag(table: Array, idx: Array, *, mode: str = "sum",
         functools.partial(_bag_kernel, bag_len=L, mode=mode),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,9 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams; local alias, no namespace mutation
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 NEG_INF = -1e30
@@ -115,7 +112,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -198,7 +195,7 @@ def flash_decode(q: Array, k: Array, v: Array, *, length: Array | int,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -2,31 +2,40 @@
 
 This is the query-time hot loop of the paper's method on TPU: each batched
 greedy-search step scores the expanded frontier's fanout against the queries
-and merges the results into the per-query pools. Two kernels:
+and merges the results into the per-query pools. Every block below is
+(8, 128)-tiled or spans its whole array, which is what the TPU compiler
+accepts. Two kernels:
 
-* ``gather_score`` — scalar-prefetched candidate ids drive the BlockSpec index
-  map, so corpus rows stream HBM→VMEM *by id* (no XLA gather materialization)
-  and the metric reduction (l2 / sqeuclidean / ip / cosine, matching
-  ``repro.core.distances``) happens in VMEM next to the data. With the
-  ``norms`` operand (the corpus-norm cache of
-  ``repro.kernels.backend.CorpusView``, packed by :func:`pack_norms`), the
-  score is computed in **matmul form** — ``‖x‖² − 2·⟨x, q⟩ + ‖q‖²`` with the
-  row-norm term streamed from the cache instead of re-reduced per lane, which
-  drops the subtract-square pass (~⅓ of the per-wave flops) and leaves one
-  fused dot per lane. Without ``norms`` the historical gather-then-reduce
-  body runs unchanged. ``gather_l2`` is the historical sqeuclidean entry
-  point, kept as an alias;
-* ``beam_merge_topk`` — bitonic merge network over the (beam ‖ fanout) pair
-  in VMEM for the whole query batch per invocation, compare-exchange
-  implemented with roll/where so it lowers to vector selects (no sort
-  primitive needed on TPU). Optionally carries an int32 payload lane
-  (the pool's ``expanded`` flags) through the same permutation network so
-  the batched engine can merge its full (ids, dists, expanded) pool state
-  in one call. The network is padded to a power of two **and to the
-  128-wide TPU lane** (``MERGE_LANE``), and the output block is
+* ``gather_score`` — a grid over tiles of :data:`QUERY_TILE` queries. XLA
+  gathers the candidate rows beside the call into a ``(B, K, dim)`` block
+  in the corpus dtype (int8/fp8 codes stay codes): the chip's compiler
+  refuses a one-row DMA out of an (8, 128)-tiled corpus, and an aligned
+  8-row (32 for int8) fetch per id would move 8x (32x) the row bytes. Each
+  tile reads its ``(QUERY_TILE, K, dim)`` block with K on sublanes, runs
+  the metric reduction (l2 / sqeuclidean / ip / cosine, matching
+  ``repro.core.distances``) per query on its ``(K, dim)`` rows, and stores
+  one lane-dense ``(K, 128)`` block whose lane b holds query b's scores
+  (transposed back to (B, K) by XLA after the call). With the ``norms``
+  operand (the row metadata of ``repro.kernels.backend.CorpusView``,
+  packed by :func:`pack_row_meta` and gathered beside the rows) the score
+  is computed in **matmul form** — ``‖x‖² − 2·⟨x, q⟩ + ‖q‖²`` with the
+  row-norm term read from the cache instead of re-reduced, which drops the
+  subtract-square pass and leaves one multiply-reduce per lane; the
+  4-column form dequantizes the codes in VMEM right before that dot.
+  Without ``norms`` the gather-then-reduce body runs.
+  ``gather_score_local`` is the shard-local form: it remaps global ids to
+  the local row block in XLA and runs the same tile;
+* ``beam_merge_topk`` — bitonic merge network over the (beam ‖ fanout) rows,
+  8 query rows per grid step over an ``(8, n_pad)`` block. Each
+  compare-exchange finds its partner lane (index XOR j) with two
+  ``pltpu.roll`` lane rotations and a select, so it lowers to vector
+  rotates and selects (no sort primitive, no reshape). Optionally carries
+  an int32 payload lane (the pool's ``expanded`` flags) through the same
+  network so the batched engine can merge its full (ids, dists, expanded)
+  pool state in one call. The network is padded to a power of two **and
+  to the 128-wide TPU lane** (``MERGE_LANE``), and the output block is
   lane-aligned too (sliced back to L outside the kernel) — so
-  non-power-of-two and non-lane-multiple pools run the fused merge instead
-  of being excluded by tiling constraints.
+  non-power-of-two and non-lane-multiple pools run the fused merge.
 
 Pure-jnp oracles for both live in ``repro.kernels.ref`` (the CPU/interpret
 fallback path used by the core engine off-TPU); backend selection for all of
@@ -48,15 +57,11 @@ Array = jax.Array
 VALID_METRICS = ("l2", "sqeuclidean", "ip", "cosine")
 
 MERGE_LANE = 128  # TPU vector lane width — merge rows are padded to it
+QUERY_TILE = 8  # queries (scoring) / pool rows (merge) per grid step
 
 
 def pack_norms(view: CorpusView) -> Array:
-    """(N, 2) f32 kernel operand: column 0 = ‖x‖², column 1 = 1/‖x‖.
-
-    One row per corpus row so the same prefetched id that streams the
-    corpus row also streams its cached norms (the BlockSpec index maps are
-    identical).
-    """
+    """(N, 2) f32 kernel operand: column 0 = ‖x‖², column 1 = 1/‖x‖."""
     return jnp.stack([view.sq_norms, view.inv_norms], axis=1)
 
 
@@ -67,8 +72,7 @@ def pack_row_meta(view: CorpusView) -> Array:
     for a raw view, ``[‖x‖², 1/‖x‖, scale, zero_point]`` for a quantized
     one (the zero-point column is 0.0 for the symmetric fp8 modes, so one
     in-tile dequant ``(code - zp) * scale`` serves int8 and fp8 alike).
-    Streams by the same prefetched id as the corpus row; the column count
-    selects the kernel body in :func:`gather_score`.
+    The column count selects the kernel body in :func:`gather_score`.
     """
     cols = [view.sq_norms, view.inv_norms]
     if view.scales is not None:
@@ -80,79 +84,50 @@ def pack_row_meta(view: CorpusView) -> Array:
 
 
 # --------------------------------------------------------------------------
-# per-lane scoring bodies — one definition each, shared by the global and
-# shard-local kernels (only the masking tail differs between those)
+# fused gather + score
 # --------------------------------------------------------------------------
-def _metric_score(q, row, *, metric: str):
-    """Gather-then-reduce per-lane score (matches ``ref.gather_score_ref``)."""
-    if metric in ("l2", "sqeuclidean"):
-        diff = q - row
-        d = jnp.sum(diff * diff)
-        return jnp.sqrt(d) if metric == "l2" else d
-    if metric == "ip":
-        return -jnp.sum(q * row)
-    # cosine
-    qn = jax.lax.rsqrt(jnp.sum(q * q) + NORM_EPS)
-    rn = jax.lax.rsqrt(jnp.sum(row * row) + NORM_EPS)
-    return 1.0 - jnp.sum(q * row) * qn * rn
+def _score_block(q, rows, meta, *, metric: str):
+    """(1, dim) query vs (K, dim) rows -> (K, 1) scores.
 
+    ``meta`` is None (gather-then-reduce, matches ``ref.gather_score_ref``)
+    or the (K, 2|4) row metadata (matmul form; 4 columns dequantize first).
+    """
+    def rsum(x):
+        return jnp.sum(x, axis=1, keepdims=True)
 
-def _metric_score_mm(q, row, nsq, ninv, *, metric: str):
-    """Matmul-form per-lane score over the cached row norms."""
-    dot = jnp.dot(row, q, preferred_element_type=jnp.float32)
+    if meta is None:
+        if metric in ("l2", "sqeuclidean"):
+            diff = q - rows
+            d = rsum(diff * diff)
+            return jnp.sqrt(d) if metric == "l2" else d
+        if metric == "ip":
+            return -rsum(q * rows)
+        qn = jax.lax.rsqrt(rsum(q * q) + NORM_EPS)
+        rn = jax.lax.rsqrt(rsum(rows * rows) + NORM_EPS)
+        return 1.0 - rsum(q * rows) * qn * rn
+    if meta.shape[1] == 4:  # in-VMEM dequant: ref.dequant_rows_ref exactly
+        rows = (rows - meta[:, 3:4]) * meta[:, 2:3]
+    dot = rsum(q * rows)
     if metric in ("l2", "sqeuclidean"):
         # the expansion can dip epsilon-negative where the oracle is ~0
-        d = jnp.maximum(nsq - 2.0 * dot + jnp.sum(q * q), 0.0)
+        d = jnp.maximum(meta[:, 0:1] - 2.0 * dot + rsum(q * q), 0.0)
         return jnp.sqrt(d) if metric == "l2" else d
     if metric == "ip":
         return -dot
-    return 1.0 - dot * jax.lax.rsqrt(jnp.sum(q * q) + NORM_EPS) * ninv
+    return 1.0 - dot * jax.lax.rsqrt(rsum(q * q) + NORM_EPS) * meta[:, 1:2]
 
 
-# --------------------------------------------------------------------------
-# fused gather + score (metric-parameterized; gather-then-reduce form)
-# --------------------------------------------------------------------------
-def _gather_score_kernel(ids_ref, q_ref, row_ref, o_ref, *, metric: str):
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)  # (dim,) — query b
-    row = row_ref[0].astype(jnp.float32)  # (dim,) — corpus[ids[b, k]]
-    d = _metric_score(q, row, metric=metric)
-    valid = ids_ref[b, k] >= 0
-    o_ref[0, 0] = jnp.where(valid, d, float("inf"))
-
-
-# --------------------------------------------------------------------------
-# matmul-form scoring tile: norms streamed from the corpus-norm cache
-# --------------------------------------------------------------------------
-def _gather_score_mm_kernel(ids_ref, q_ref, row_ref, nrm_ref, o_ref, *,
-                            metric: str):
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    row = row_ref[0].astype(jnp.float32)
-    d = _metric_score_mm(q, row, nrm_ref[0, 0], nrm_ref[0, 1], metric=metric)
-    valid = ids_ref[b, k] >= 0
-    o_ref[0, 0] = jnp.where(valid, d, float("inf"))
-
-
-def _gather_score_mm_quant_kernel(ids_ref, q_ref, row_ref, nrm_ref, o_ref, *,
-                                  metric: str):
-    """Matmul-form tile over quantized rows: dequant in-register.
-
-    ``row_ref`` streams the int8/fp8 codes (the HBM traffic is the codes,
-    not f32); the dequant ``(code - zp) * scale`` runs on the VMEM-resident
-    vector right before the dot, with scale/zp from columns 2/3 of the
-    row-metadata operand — ``ref.dequant_rows_ref`` semantics exactly, and
-    the cached norms (columns 0/1) already describe the dequantized row.
-    """
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    row = (row_ref[0].astype(jnp.float32) - nrm_ref[0, 3]) * nrm_ref[0, 2]
-    d = _metric_score_mm(q, row, nrm_ref[0, 0], nrm_ref[0, 1], metric=metric)
-    valid = ids_ref[b, k] >= 0
-    o_ref[0, 0] = jnp.where(valid, d, float("inf"))
+def _gather_score_kernel(q_ref, rows_ref, *refs, metric: str,
+                         with_meta: bool):
+    meta_ref, o_ref = refs if with_meta else (None, refs[0])
+    k_pad = rows_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (k_pad, MERGE_LANE), 1)
+    out = jnp.zeros((k_pad, MERGE_LANE), jnp.float32)
+    for b in range(QUERY_TILE):
+        d = _score_block(q_ref[b:b + 1, :], rows_ref[b].astype(jnp.float32),
+                         meta_ref[b] if with_meta else None, metric=metric)
+        out = jnp.where(lane == b, d, out)
+    o_ref[0] = out
 
 
 def gather_score(corpus: Array, queries: Array, ids: Array, *,
@@ -164,93 +139,42 @@ def gather_score(corpus: Array, queries: Array, ids: Array, *,
     match ``repro.core.distances`` ("ip" is negated, "cosine" is one-minus).
     With ``norms`` (the packed (N, 2) or (N, 4) row-metadata operand, see
     :func:`pack_row_meta`) the matmul-form tile runs — the row-norm reduce
-    is replaced by a cached load streamed by the same prefetched id; the
-    4-column form additionally dequantizes int8/fp8 codes in-register
-    before the dot.
+    is replaced by a cached load; the 4-column form additionally
+    dequantizes int8/fp8 codes in VMEM before the dot.
     """
     if metric not in VALID_METRICS:
         raise ValueError(f"metric must be one of {VALID_METRICS}, got {metric!r}")
     b, dim = queries.shape
     k = ids.shape[1]
-    in_specs = [
-        pl.BlockSpec((1, dim), lambda bi, ki, ids: (bi, 0)),
-        # the gather: block row chosen by the prefetched id
-        pl.BlockSpec(
-            (1, dim),
-            lambda bi, ki, ids: (jnp.maximum(ids[bi, ki], 0), 0),
-        ),
-    ]
-    operands = [queries, corpus]
-    if norms is None:
-        kernel = functools.partial(_gather_score_kernel, metric=metric)
-    else:
+    # K rides the sublanes: pad it to the corpus dtype's packed tile height
+    # (8 rows for 32-bit, 16 for 16-bit, 32 for 8-bit codes)
+    sub = 32 // jnp.dtype(corpus.dtype).itemsize
+    k_pad = -(-k // sub) * sub
+    b_pad = -(-b // QUERY_TILE) * QUERY_TILE
+    n_tiles = b_pad // QUERY_TILE
+    safe = jnp.pad(jnp.maximum(ids, 0).astype(jnp.int32),
+                   ((0, b_pad - b), (0, k_pad - k)))
+    operands = [jnp.pad(queries.astype(jnp.float32), ((0, b_pad - b), (0, 0))),
+                corpus[safe]]
+    in_specs = [pl.BlockSpec((QUERY_TILE, dim), lambda t: (t, 0)),
+                pl.BlockSpec((QUERY_TILE, k_pad, dim), lambda t: (t, 0, 0))]
+    if norms is not None:
         ncols = norms.shape[1]
-        body = (_gather_score_mm_kernel if ncols == 2
-                else _gather_score_mm_quant_kernel)
-        kernel = functools.partial(body, metric=metric)
-        # the row metadata streams by the same prefetched id as the row
-        in_specs.append(pl.BlockSpec(
-            (1, ncols), lambda bi, ki, ids: (jnp.maximum(ids[bi, ki], 0), 0)))
-        operands.append(norms.astype(jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, k),
+        operands.append(norms.astype(jnp.float32)[safe])
+        in_specs.append(pl.BlockSpec((QUERY_TILE, k_pad, ncols),
+                                     lambda t: (t, 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_gather_score_kernel, metric=metric,
+                          with_meta=norms is not None),
+        grid=(n_tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda bi, ki, ids: (bi, ki)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
+        out_specs=pl.BlockSpec((1, k_pad, MERGE_LANE), lambda t: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, k_pad, MERGE_LANE),
+                                       jnp.float32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), *operands)
-
-
-def gather_l2(corpus: Array, queries: Array, ids: Array, *,
-              interpret: bool = False) -> Array:
-    """corpus (N, dim); queries (B, dim); ids (B, K) -> (B, K) sq-l2 dists."""
-    return gather_score(corpus, queries, ids, metric="sqeuclidean",
-                        interpret=interpret)
-
-
-def _gather_score_local_kernel(off_ref, ids_ref, q_ref, row_ref, o_ref, *,
-                               metric: str, n_local: int):
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    row = row_ref[0].astype(jnp.float32)
-    d = _metric_score(q, row, metric=metric)
-    loc = ids_ref[b, k] - off_ref[0]
-    owned = (ids_ref[b, k] >= 0) & (loc >= 0) & (loc < n_local)
-    # psum identity on foreign/padding lanes — see ref.gather_score_local_ref
-    o_ref[0, 0] = jnp.where(owned, d, 0.0)
-
-
-def _gather_score_local_mm_kernel(off_ref, ids_ref, q_ref, row_ref, nrm_ref,
-                                  o_ref, *, metric: str, n_local: int):
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    row = row_ref[0].astype(jnp.float32)
-    d = _metric_score_mm(q, row, nrm_ref[0, 0], nrm_ref[0, 1], metric=metric)
-    loc = ids_ref[b, k] - off_ref[0]
-    owned = (ids_ref[b, k] >= 0) & (loc >= 0) & (loc < n_local)
-    o_ref[0, 0] = jnp.where(owned, d, 0.0)
-
-
-def _gather_score_local_mm_quant_kernel(off_ref, ids_ref, q_ref, row_ref,
-                                        nrm_ref, o_ref, *, metric: str,
-                                        n_local: int):
-    # shard-local twin of _gather_score_mm_quant_kernel: in-register dequant
-    # of the streamed codes, psum identity on foreign/padding lanes
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    row = (row_ref[0].astype(jnp.float32) - nrm_ref[0, 3]) * nrm_ref[0, 2]
-    d = _metric_score_mm(q, row, nrm_ref[0, 0], nrm_ref[0, 1], metric=metric)
-    loc = ids_ref[b, k] - off_ref[0]
-    owned = (ids_ref[b, k] >= 0) & (loc >= 0) & (loc < n_local)
-    o_ref[0, 0] = jnp.where(owned, d, 0.0)
+    )(*operands)
+    d = out[:, :k, :QUERY_TILE].transpose(0, 2, 1).reshape(b_pad, k)[:b]
+    return jnp.where(ids >= 0, d, jnp.inf)
 
 
 def gather_score_local(corpus_local: Array, queries: Array, ids: Array,
@@ -261,94 +185,55 @@ def gather_score_local(corpus_local: Array, queries: Array, ids: Array,
 
     ``corpus_local`` (n_local, dim) is this shard's contiguous row block
     starting at global row ``offset`` (a traced scalar — inside ``shard_map``
-    it is ``axis_index * n_local``). Owned lanes stream their local row
-    HBM→VMEM by remapped id exactly like :func:`gather_score`; foreign and
-    padding lanes emit the psum identity 0.0. ``norms`` is the *local*
-    block's packed row metadata ((n_local, 2) raw / (n_local, 4)
-    quantized — it shards with the rows) and selects the matmul-form tile,
-    with in-register dequant for the 4-column form.
+    it is ``axis_index * n_local``). Owned lanes are remapped to local ids
+    and scored by the :func:`gather_score` tile, so they carry exactly its
+    per-lane value; foreign and padding lanes are masked to the psum
+    identity 0.0. ``norms`` is the *local* block's packed row metadata
+    ((n_local, 2) raw / (n_local, 4) quantized — it shards with the rows).
     """
-    if metric not in VALID_METRICS:
-        raise ValueError(f"metric must be one of {VALID_METRICS}, got {metric!r}")
-    b, dim = queries.shape
-    k = ids.shape[1]
     n_local = corpus_local.shape[0]
-    offset = jnp.asarray(offset, jnp.int32).reshape(1)
-    in_specs = [
-        pl.BlockSpec((1, dim), lambda bi, ki, off, ids: (bi, 0)),
-        # the gather: local block row chosen by the remapped global id
-        pl.BlockSpec(
-            (1, dim),
-            lambda bi, ki, off, ids: (
-                jnp.clip(ids[bi, ki] - off[0], 0, n_local - 1), 0),
-        ),
-    ]
-    operands = [queries, corpus_local]
-    if norms is None:
-        kernel = functools.partial(_gather_score_local_kernel, metric=metric,
-                                   n_local=n_local)
-    else:
-        ncols = norms.shape[1]
-        body = (_gather_score_local_mm_kernel if ncols == 2
-                else _gather_score_local_mm_quant_kernel)
-        kernel = functools.partial(body, metric=metric, n_local=n_local)
-        in_specs.append(pl.BlockSpec(
-            (1, ncols),
-            lambda bi, ki, off, ids: (
-                jnp.clip(ids[bi, ki] - off[0], 0, n_local - 1), 0)))
-        operands.append(norms.astype(jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # offset, then the candidate ids
-        grid=(b, k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda bi, ki, off, ids: (bi, ki)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
-        interpret=interpret,
-    )(offset, ids.astype(jnp.int32), *operands)
+    ids = ids.astype(jnp.int32)
+    loc = ids - jnp.asarray(offset, jnp.int32)
+    owned = (ids >= 0) & (loc >= 0) & (loc < n_local)
+    d = gather_score(corpus_local, queries, jnp.where(owned, loc, -1),
+                     metric=metric, norms=norms, interpret=interpret)
+    # psum identity on foreign/padding lanes — see ref.gather_score_local_ref
+    return jnp.where(owned, d, 0.0)
 
 
 # --------------------------------------------------------------------------
 # bitonic beam merge
 # --------------------------------------------------------------------------
-def _xor_permute(x: Array, j: int) -> Array:
-    """x (1, n) -> x with lanes permuted by index XOR j (j a power of two).
-
-    Implemented as a static reshape + flip (pairs of j-strided halves), which
-    lowers to vector shuffles on TPU — no dynamic gather.
-    """
-    n = x.shape[1]
-    return x.reshape(n // (2 * j), 2, j)[:, ::-1, :].reshape(1, n)
-
-
-def _merge_kernel(bi_ref, bd_ref, bf_ref, ci_ref, cd_ref, cf_ref,
-                  oi_ref, od_ref, of_ref, *, n: int):
-    d = jnp.concatenate([bd_ref[...], cd_ref[...]], axis=1).astype(jnp.float32)
-    idx = jnp.concatenate([bi_ref[...], ci_ref[...]], axis=1)
-    flg = jnp.concatenate([bf_ref[...], cf_ref[...]], axis=1)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    # full bitonic sort (ascending) of the 2^m-length sequence
+def _merge_kernel(d_ref, i_ref, f_ref, od_ref, oi_ref, of_ref):
+    d, idx, flg = d_ref[...], i_ref[...], f_ref[...]
+    n = d.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    # full bitonic sort (ascending) of the 2^m-length rows
     m = n.bit_length() - 1
     for stage in range(1, m + 1):
-        span = 1 << stage
-        desc = (pos & span) != 0
         for sub in range(stage - 1, -1, -1):
             j = 1 << sub
-            d_p = _xor_permute(d, j)
-            i_p = _xor_permute(idx, j)
-            f_p = _xor_permute(flg, j)
-            is_lo = (pos & j) == 0
-            want_min = desc ^ is_lo
-            take_self = jnp.where(want_min, d <= d_p, d >= d_p)
+            # partner lane = pos XOR j, i.e. pos + j or pos - j: rotate both
+            # ways and keep the rotation whose source lane is the partner
+            # (rolling the lane iota makes this independent of the rotate
+            # direction convention)
+            fwd = pltpu.roll(pos, j, 1) == (pos ^ j)
+
+            def partner(x, j=j, fwd=fwd):
+                return jnp.where(fwd, pltpu.roll(x, j, 1),
+                                 pltpu.roll(x, n - j, 1))
+
+            d_p, i_p, f_p = partner(d), partner(idx), partner(flg)
+            # keep the min where the lane's half (bit ``sub``) agrees with
+            # its segment's direction (bit ``stage``: set = descending)
+            want_min = (((pos >> stage) ^ (pos >> sub)) & 1) == 0
+            take_self = (want_min & (d <= d_p)) | (~want_min & (d >= d_p))
             d = jnp.where(take_self, d, d_p)
             idx = jnp.where(take_self, idx, i_p)
             flg = jnp.where(take_self, flg, f_p)
-    w = oi_ref.shape[1]
+    w = od_ref.shape[1]
+    od_ref[...] = d[:, :w]
     oi_ref[...] = idx[:, :w]
-    od_ref[...] = d[:, :w].astype(od_ref.dtype)
     of_ref[...] = flg[:, :w]
 
 
@@ -357,7 +242,7 @@ def beam_merge_topk(beam_ids: Array, beam_dists: Array, cand_ids: Array,
                     cand_flags: Array | None = None, interpret: bool = False):
     """Merge (B, L) beam and (B, K) candidates -> best-(B, L). Bitonic in VMEM.
 
-    One invocation handles the whole query batch (grid over B). When
+    The grid runs over tiles of :data:`QUERY_TILE` pool rows. When
     ``beam_flags`` is given, an int32 payload lane rides through the same
     compare-exchange network (the batched engine's ``expanded`` markers) and
     a third output is returned. Ties in distance (inf padding included) are
@@ -382,42 +267,33 @@ def beam_merge_topk(beam_ids: Array, beam_dists: Array, cand_ids: Array,
     n = L + k
     # power-of-two for the bitonic network, lane width for the TPU tiling
     n_pad = max(1 << (n - 1).bit_length(), MERGE_LANE)
-    pad = n_pad - n
-    if pad:
-        cand_ids = jnp.pad(cand_ids, ((0, 0), (0, pad)), constant_values=-1)
-        cand_dists = jnp.pad(cand_dists, ((0, 0), (0, pad)),
-                             constant_values=jnp.inf)
-        cand_flags = jnp.pad(cand_flags, ((0, 0), (0, pad)))
-        k = k + pad
+    b_pad = -(-b // QUERY_TILE) * QUERY_TILE
+    pad = ((0, b_pad - b), (0, n_pad - n))
+    d = jnp.pad(jnp.concatenate([beam_dists.astype(jnp.float32),
+                                 cand_dists.astype(jnp.float32)], axis=1),
+                pad, constant_values=jnp.inf)
+    idx = jnp.pad(jnp.concatenate([beam_ids.astype(jnp.int32),
+                                   cand_ids.astype(jnp.int32)], axis=1),
+                  pad, constant_values=-1)
+    flg = jnp.pad(jnp.concatenate([beam_flags.astype(jnp.int32),
+                                   cand_flags.astype(jnp.int32)], axis=1),
+                  pad)
     # lane-aligned output block, sliced back to L below
     w = min(n_pad, -(-L // MERGE_LANE) * MERGE_LANE)
-    kernel = functools.partial(_merge_kernel, n=n_pad)
-    oi, od, of = pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, L), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, L), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, k), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, k), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, k), lambda bi: (bi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, w), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, w), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, w), lambda bi: (bi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, w), beam_ids.dtype),
-            jax.ShapeDtypeStruct((b, w), d_dtype),
-            jax.ShapeDtypeStruct((b, w), jnp.int32),
-        ],
+    in_spec = pl.BlockSpec((QUERY_TILE, n_pad), lambda t: (t, 0))
+    out_spec = pl.BlockSpec((QUERY_TILE, w), lambda t: (t, 0))
+    od, oi, of = pl.pallas_call(
+        _merge_kernel,
+        grid=(b_pad // QUERY_TILE,),
+        in_specs=[in_spec] * 3,
+        out_specs=[out_spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct((b_pad, w), jnp.float32),
+                   jax.ShapeDtypeStruct((b_pad, w), jnp.int32),
+                   jax.ShapeDtypeStruct((b_pad, w), jnp.int32)],
         interpret=interpret,
-    )(beam_ids, beam_dists.astype(jnp.float32),
-      beam_flags.astype(jnp.int32), cand_ids,
-      cand_dists.astype(jnp.float32), cand_flags.astype(jnp.int32))
-    oi, od, of = oi[:, :L], od[:, :L], of[:, :L]
+    )(d, idx, flg)
+    oi = oi[:b, :L].astype(beam_ids.dtype)
+    od = od[:b, :L].astype(d_dtype)
     if with_flags:
-        return oi, od, of
+        return oi, od, of[:b, :L]
     return oi, od

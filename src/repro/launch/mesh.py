@@ -13,59 +13,8 @@ from __future__ import annotations
 import jax
 
 
-def axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the installed jax has AxisType.
-
-    Older jaxlibs (< 0.5) predate ``jax.sharding.AxisType``; meshes there
-    are implicitly Auto, so omitting the kwarg is the exact equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` (always Auto axis types)."""
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
-
-
-def axis_size(axis_name) -> int:
-    """Version-portable ``jax.lax.axis_size`` (static size of a named axis).
-
-    Older jax spells it ``jax.core.axis_frame(name)`` (which returns the
-    size directly, or a frame object with ``.size`` on some releases).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``jax.shard_map`` with replication checking off.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_host_mesh(n_data: int = 2, n_model: int = 4):
-    """Small mesh over whatever local devices exist (tests/examples)."""
-    n = len(jax.devices())
-    n_model = min(n_model, n)
-    n_data = max(1, min(n_data, n // n_model))
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

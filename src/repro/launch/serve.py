@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.configs import bimetric_paper, qwen3_0_6b
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.engine import BiMetricEngine, EmbedTower, SearchRequest
 
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--quota", type=int, default=32)
     ap.add_argument("--seq", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
 
     key = jax.random.PRNGKey(0)
     cheap_cfg = qwen3_0_6b.smoke()

@@ -119,9 +119,8 @@ def test_all_archs_have_four_shapes():
 def test_smoke_cells_lower_on_host_mesh():
     """Every cell's step function lowers with the SMOKE config on a 1-device
     mesh — catches abstract-args/step signature mismatches cheaply."""
-    from repro.launch.mesh import make_mesh
-
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in ["qwen3-0.6b", "bst", "bert4rec"]:
         spec = get_arch(arch)
         for shape in spec.shapes:

@@ -19,7 +19,11 @@ def _run(body: str) -> str:
         import numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import make_mesh, shard_map
+        from jax.sharding import AxisType
+        shard_map = partial(jax.shard_map, check_vma=False)
+        def make_mesh(shape, axes, devices=None):
+            return jax.make_mesh(shape, axes, devices=devices,
+                                 axis_types=(AxisType.Auto,) * len(axes))
         mesh = make_mesh((8,), ("x",))
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -178,9 +182,7 @@ def test_elastic_checkpoint_reshard(tmp_path):
         tree = {{"w": jax.device_put(arr, sh8)}}
         mgr = CheckpointManager("{tmp_path}", keep=2)
         mgr.save(1, tree, async_=False)
-        from repro.launch.mesh import axis_types_kw
-        mesh4 = jax.make_mesh((4,), ("y",), devices=jax.devices()[:4],
-                              **axis_types_kw(1))
+        mesh4 = make_mesh((4,), ("y",), jax.devices()[:4])
         sh4 = NamedSharding(mesh4, P(None, "y"))
         restored, _ = mgr.restore(
             {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}},
