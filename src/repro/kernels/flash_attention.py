@@ -116,6 +116,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out.reshape(b, h, sqp, dv)[:, :, :sq]
 
@@ -199,5 +200,6 @@ def flash_decode(q: Array, k: Array, v: Array, *, length: Array | int,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(qp, kp, vp, lens)
     return out.reshape(b, h, dv)

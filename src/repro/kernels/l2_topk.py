@@ -172,6 +172,7 @@ def gather_score(corpus: Array, queries: Array, ids: Array, *,
         out_shape=jax.ShapeDtypeStruct((n_tiles, k_pad, MERGE_LANE),
                                        jnp.float32),
         interpret=interpret,
+        name="l2_gather_score",
     )(*operands)
     d = out[:, :k, :QUERY_TILE].transpose(0, 2, 1).reshape(b_pad, k)[:b]
     return jnp.where(ids >= 0, d, jnp.inf)
@@ -291,6 +292,7 @@ def beam_merge_topk(beam_ids: Array, beam_dists: Array, cand_ids: Array,
                    jax.ShapeDtypeStruct((b_pad, w), jnp.int32),
                    jax.ShapeDtypeStruct((b_pad, w), jnp.int32)],
         interpret=interpret,
+        name="l2_beam_merge_topk",
     )(d, idx, flg)
     oi = oi[:b, :L].astype(beam_ids.dtype)
     od = od[:b, :L].astype(d_dtype)

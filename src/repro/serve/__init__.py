@@ -51,8 +51,16 @@ Observability: ``ServeStats`` splits per-request latency into ``queue_ms``
 (submit → slot admission) + ``compute_ms`` (admission → resolve), with
 ``latency_ms`` their sum, plus admission-time ``slot_occupancy`` /
 ``queue_depth`` snapshots; ``BiMetricEngine.counters()`` exposes the
-cumulative ``EngineCounters`` (submitted / admitted / completed /
-cancelled / deadline_misses and instantaneous depth/occupancy).
+cumulative ``EngineCounters``: submitted / admitted / completed /
+cancelled / deadline_misses and instantaneous depth/occupancy; the slot
+drive's stage-2 ``waves`` and the ``doc_lookups`` they charged; the
+expensive tower's ``drained_rows`` and ``drain_batches``; each tower's
+rows computed and rows holding a token; and, per ``serve.*`` span, how
+many closed (``span_n``) and their host seconds (``span_s``). The slot
+drive opens those spans (admission, cheap embed, stage 1, the query-embed
+wait, each wave with its plan, drain wait, gather and commit, resolution;
+the tower lane's drains and query embeds) as ``jax.profiler``
+host spans, so a profiler trace puts them on the device's clock.
 ``close()`` cancels still-queued requests (``CancelledError``) instead of
 flushing them; admitted slots still resolve. The device-side kernel route
 is the ``backend=`` knob (``repro.kernels``).

@@ -49,6 +49,7 @@ and pool-capacity growth are all invisible to a request's answer.
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import dataclasses
 import functools
 import heapq
@@ -165,10 +166,6 @@ class SearchRequest:
 class ServeStats:
     d_calls: int = 0
     D_calls: int = 0  # expensive-tower document scorings (the budget)
-    # forward-pass batches the engine drained during this request's
-    # residency (slot drive: shared across co-resident slots; sync drive:
-    # the whole batch's drains, replicated per row — do not sum)
-    tower_batches: int = 0
     # async slot drive only: submit -> slot-admission wait, and admission ->
     # future-resolution compute. Both 0.0 on the synchronous drives, which
     # have no queueing to measure.
@@ -202,7 +199,7 @@ class SearchResult(NamedTuple):
 
 @dataclasses.dataclass
 class EngineCounters:
-    """Cumulative admission-layer observability (:meth:`BiMetricEngine.counters`)."""
+    """Cumulative serving observability (:meth:`BiMetricEngine.counters`)."""
 
     submitted: int = 0
     admitted: int = 0
@@ -217,16 +214,46 @@ class EngineCounters:
     degraded: int = 0  # requests resolved degraded (ServeStats.degraded)
     shed: int = 0  # requests failed fast by tower-down policy "fail"
     breaker_opens: int = 0  # breaker closed->open transitions (snapshot)
+    # stage-2 waves of the slot drive (``serve.wave`` spans: steps and
+    # admission entry waves) and the document ids they charged
+    # (``safe[keep]``, so Σ ``ServeStats.D_calls`` of the served requests)
+    waves: int = 0
+    doc_lookups: int = 0
+    # expensive-tower drains, both drives: uncached documents embedded and
+    # the forward batches they took
+    drained_rows: int = 0
+    drain_batches: int = 0
+    # rows each tower's program computed (padding included) and those that
+    # held a token, per chunk of ``EmbedTower.embed``; read in from the
+    # towers (a tower shared by engines counts for all of them; a tower
+    # that is not an EmbedTower leaves them 0)
+    cheap_rows: int = 0
+    cheap_rows_useful: int = 0
+    expensive_rows: int = 0
+    expensive_rows_useful: int = 0
+    # per ``serve.*`` span name: spans closed, and their host seconds
+    span_n: dict = dataclasses.field(default_factory=dict)
+    span_s: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
 class EmbedTower:
     params: dict
     cfg: T.TransformerConfig
+    # rows the program computed, and those holding a token (see row_counts)
+    rows: int = dataclasses.field(default=0, init=False, compare=False)
+    rows_useful: int = dataclasses.field(default=0, init=False, compare=False)
+    _rows_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, init=False, repr=False, compare=False)
 
     def __post_init__(self):
-        self._embed = jax.jit(
-            lambda p, toks: T.embed_pool(p, toks, self.cfg))
+        def program(p, toks):
+            return T.embed_pool(p, toks, self.cfg)
+
+        # the device trace names the program jit_<name>: one per tower shape
+        program.__name__ = program.__qualname__ = (
+            f"embed_pool_d{self.cfg.d_model}_l{self.cfg.n_layers}")
+        self._embed = jax.jit(program)
 
     def embed(self, tokens: np.ndarray, batch: int = 64) -> np.ndarray:
         out = []
@@ -234,8 +261,19 @@ class EmbedTower:
         pad = (-n) % batch
         toks = np.pad(tokens, ((0, pad), (0, 0))) if pad else tokens
         for s in range(0, len(toks), batch):
-            out.append(np.asarray(self._embed(self.params, toks[s:s + batch])))
+            chunk = toks[s:s + batch]
+            useful = int(np.count_nonzero(chunk.any(axis=1)))
+            out.append(np.asarray(self._embed(self.params, chunk)))
+            with self._rows_lock:
+                self.rows += chunk.shape[0]
+                self.rows_useful += useful
         return np.concatenate(out)[:n]
+
+    def row_counts(self) -> tuple[int, int]:
+        """(rows computed, rows holding a token): padding to the batch and
+        all-zero empty slots are computed but hold none."""
+        with self._rows_lock:
+            return self.rows, self.rows_useful
 
 
 class ServeFuture(concurrent.futures.Future):
@@ -278,7 +316,6 @@ class _Active:
     pend: _Pending
     t_admit: float
     d_calls: int
-    tower0: int  # pool drain counter at admission
     occ_snap: int
     depth_snap: int
     # stage-1 proxy pool row (ids sorted by d-dist; vamana only) — the
@@ -389,6 +426,30 @@ class _SlotPool:
     mixed workloads retrace log-many times; growth is an exact semantic
     no-op (``repro.core.beam.grow_state``). All methods run on the drive
     thread only.
+
+    Spans (:meth:`BiMetricEngine._span`), each also counted in
+    ``EngineCounters.span_n`` / ``span_s``; each opens and closes around
+    code already there and adds no host sync of its own:
+
+    * ``serve.admit`` (``group``, ``requests``) — :meth:`prepare`, on its
+      own or inside a wave's drain; in it ``serve.cheap_embed``,
+      ``serve.stage1`` (through its host reads of the pools) and
+      ``serve.query_wait`` (the expensive query embeds), each ``group``;
+    * ``serve.wave`` (``wave``, ``entry``) — one :meth:`step` /
+      :meth:`step_ct`, or the entry wave of :meth:`admit` (``entry=1``);
+      in it ``serve.plan`` (the plan or ``reset_slots`` dispatch through
+      the host read of its ids), ``serve.drain_wait``, ``serve.gather``
+      and ``serve.commit``, each ``wave``. The commit runs on the device
+      after its span closes: the host next waits for it at
+      :meth:`resolve_finished`'s active-mask read, or else, the device
+      running its programs in order, in the next ``serve.plan``;
+    * ``serve.resolve`` (``resolved``) — :meth:`resolve_finished` when
+      rows finish.
+
+    The tower lane's spans carry the id of the wave or group that asked
+    for them: ``serve.tower.drain`` (``wave``; ``rows``, the wave's
+    document lookups, cached ones included) and
+    ``serve.tower.query_embed`` (``group``).
     """
 
     def __init__(self, eng: "BiMetricEngine"):
@@ -409,7 +470,7 @@ class _SlotPool:
         self.dedup: str | None = None
         self.cap: int | None = None
         self.ew_cap = 1
-        self.tower_total = 0
+        self.groups = 0  # admission groups staged (the span id)
         self.prepared: _Prepared | None = None
         # rows whose future already resolved early (mid-flight deadline /
         # degradation while a wave was in flight): freed only at the next
@@ -433,34 +494,38 @@ class _SlotPool:
         path needs no expensive embeddings at all. While the tower lane is
         open-circuit under ``"degrade"``, the group short-circuits to
         proxy-only serving without ever occupying a slot."""
-        try:
-            return self._prepare_inner(group)
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except BaseException as exc:
-            tower = isinstance(exc, TowerFailure)
-            shed = 0
-            for pend in group:
-                if pend.future.done():
-                    continue  # failed individually (malformed tokens)
-                if tower:
-                    # the lane (not the group) is the failure: keep the
-                    # class so callers can tell outage from bad input
-                    err = TowerFailure(
-                        "expensive-tower lane unavailable at admission "
-                        "(see __cause__)")
-                else:
-                    err = AdmissionFailed(
-                        "admission group failed before slot residency "
-                        "(see __cause__)")
-                err.__cause__ = exc
-                pend.future._fail(err)
-                shed += 1
-            with self.eng._mu:
-                self.eng._counters.shed += shed
-            return None
+        self.groups += 1
+        with self.eng._span("serve.admit", group=self.groups,
+                            requests=len(group)):
+            try:
+                return self._prepare_inner(group, self.groups)
+            except (KeyboardInterrupt, SystemExit):
+                raise
+            except BaseException as exc:
+                tower = isinstance(exc, TowerFailure)
+                shed = 0
+                for pend in group:
+                    if pend.future.done():
+                        continue  # failed individually (malformed tokens)
+                    if tower:
+                        # the lane (not the group) is the failure: keep the
+                        # class so callers can tell outage from bad input
+                        err = TowerFailure(
+                            "expensive-tower lane unavailable at admission "
+                            "(see __cause__)")
+                    else:
+                        err = AdmissionFailed(
+                            "admission group failed before slot residency "
+                            "(see __cause__)")
+                    err.__cause__ = exc
+                    pend.future._fail(err)
+                    shed += 1
+                with self.eng._mu:
+                    self.eng._counters.shed += shed
+                return None
 
-    def _prepare_inner(self, group: list[_Pending]) -> _Prepared | None:
+    def _prepare_inner(self, group: list[_Pending],
+                       gid: int) -> _Prepared | None:
         eng = self.eng
         seq = eng.corpus_tokens.shape[1]
         slots = np.nonzero(~self.occupied)[0][:len(group)]
@@ -492,16 +557,17 @@ class _SlotPool:
                 raise TowerFailure(
                     "expensive-tower lane is open-circuit and the "
                     "covertree index has no proxy stage to degrade to")
-            qfut = eng._tower_submit(("embed_queries", tokens))
+            item = ("embed_queries", tokens, gid)
+            qfut = eng._tower_submit(item)
             root = np.asarray(eng._flat.root_ids, np.int32)
             seeds = np.full((self.S, root.shape[0]), -1, np.int32)
             for _, slot in valid:
                 seeds[slot] = root
+            with eng._span("serve.query_wait", group=gid):
+                q_D = np.asarray(eng._tower_result(qfut, item, pool=self))
             return _Prepared(
                 valid=valid, seeds=seeds, quota=quota_g, nseed=nseed_g,
-                d_calls=np.zeros(self.S, np.int32),
-                q_D=np.asarray(eng._tower_result(
-                    qfut, ("embed_queries", tokens), pool=self)))
+                d_calls=np.zeros(self.S, np.int32), q_D=q_D)
         if blocked and eng.on_tower_failure == "fail":
             raise TowerFailure(
                 "expensive-tower lane is open-circuit "
@@ -511,32 +577,34 @@ class _SlotPool:
         # stage-1 proxy search run here meanwhile. Fixed (S, seq) shapes
         # with zero-pad rows keep per-row embeddings bit-exact regardless
         # of group composition (the tower pads to its own batch anyway).
-        qfut = (None if degrade_only
-                else eng._tower_submit(("embed_queries", tokens)))
+        item = ("embed_queries", tokens, gid)
+        qfut = None if degrade_only else eng._tower_submit(item)
         if eng._faults is not None:
             eng._faults.fire("cheap_embed")
-        q_d = jnp.asarray(eng.cheap.embed(tokens))
+        with eng._span("serve.cheap_embed", group=gid):
+            q_d = jnp.asarray(eng.cheap.embed(tokens))
         width1 = np.where(quota_g > 0, np.maximum(32, nseed_g), 1
                           ).astype(np.int32)
         pool1 = _round_capacity(int(max(width1.max(), nseed_g.max())))
-        res1 = eng._stage1(
-            q_d, width=jnp.asarray(width1), pool=pool1,
-            max_steps=jnp.asarray(4 * width1 * (quota_g > 0)))
-        lane = np.arange(res1.pool_ids.shape[1], dtype=np.int32)
-        seed_cap = _round_capacity(int(nseed_g.max()))
-        seeds = np.asarray(jnp.where(
-            jnp.asarray(lane[None, :] < nseed_g[:, None]),
-            res1.pool_ids, -1))[:, :seed_cap]
-        proxy_ids = np.asarray(res1.pool_ids)
-        proxy_dists = np.asarray(res1.pool_dists)
-        d_calls = np.asarray(res1.n_calls)
+        with eng._span("serve.stage1", group=gid):
+            res1 = eng._stage1(
+                q_d, width=jnp.asarray(width1), pool=pool1,
+                max_steps=jnp.asarray(4 * width1 * (quota_g > 0)))
+            lane = np.arange(res1.pool_ids.shape[1], dtype=np.int32)
+            seed_cap = _round_capacity(int(nseed_g.max()))
+            seeds = np.asarray(jnp.where(
+                jnp.asarray(lane[None, :] < nseed_g[:, None]),
+                res1.pool_ids, -1))[:, :seed_cap]
+            proxy_ids = np.asarray(res1.pool_ids)
+            proxy_dists = np.asarray(res1.pool_dists)
+            d_calls = np.asarray(res1.n_calls)
         if degrade_only:
             self._finish_degraded_group(valid, proxy_ids, proxy_dists,
                                         d_calls)
             return None
         try:
-            q_D = np.asarray(eng._tower_result(
-                qfut, ("embed_queries", tokens), pool=self))
+            with eng._span("serve.query_wait", group=gid):
+                q_D = np.asarray(eng._tower_result(qfut, item, pool=self))
         except (KeyboardInterrupt, SystemExit):
             raise
         except BaseException:
@@ -603,7 +671,6 @@ class _SlotPool:
         for pend, s in prep.valid:
             self.active_req[s] = _Active(
                 pend=pend, t_admit=now, d_calls=int(prep.d_calls[s]),
-                tower0=self.tower_total,
                 occ_snap=int(self.occupied.sum()), depth_snap=depth,
                 proxy_ids=(None if prep.proxy_ids is None
                            else prep.proxy_ids[s].copy()),
@@ -657,15 +724,21 @@ class _SlotPool:
         reset = np.zeros(self.S, bool)
         for _, s in prep.valid:
             reset[s] = True
-        quota_j = jnp.asarray(self.quota)
-        if eng._stepper is not None:
-            self.state, safe, keep = eng._stepper.admit(
-                self.state, reset, prep.seeds, quota_j)
-        else:
-            self.state, safe, keep = _admit_j(
-                self.state, jnp.asarray(reset), jnp.asarray(prep.seeds),
-                quota_j)
-        self._drain_and_commit(safe, keep)
+        wave = self._next_wave()
+        with eng._span("serve.wave", wave=wave, entry=1):
+            with eng._span("serve.plan", wave=wave):
+                quota_j = jnp.asarray(self.quota)
+                if eng._stepper is not None:
+                    self.state, safe, keep = eng._stepper.admit(
+                        self.state, reset, prep.seeds, quota_j)
+                else:
+                    self.state, safe, keep = _admit_j(
+                        self.state, jnp.asarray(reset),
+                        jnp.asarray(prep.seeds), quota_j)
+                safe_np, keep_np = np.asarray(safe), np.asarray(keep)
+            if self._finish_wave(wave, safe, keep, safe_np, keep_np,
+                                 overlap=False):
+                self.sweep_early()
         with eng._mu:
             eng._counters.admitted += len(prep.valid)
             eng._counters.slot_occupancy = int(self.occupied.sum())
@@ -681,13 +754,20 @@ class _SlotPool:
             if group:
                 self.prepared = self.prepare(group)
 
-    def _drain_wave(self, ids: np.ndarray, *, overlap: bool) -> int | None:
+    def _next_wave(self) -> int:
+        """Count one more wave; its number is the wave's span id."""
+        with self.eng._mu:
+            self.eng._counters.waves += 1
+            return self.eng._counters.waves
+
+    def _drain_wave(self, ids: np.ndarray, wave: int, *,
+                    overlap: bool) -> bool:
         """One wave drain through the tower lane with bounded
         exponential-backoff retries (transient failures) and breaker
-        accounting. Returns the drained batch count, or ``None`` when the
-        lane gave up — breaker open, retries exhausted, non-retryable
-        error, or drain timeout — with the terminal exception stashed for
-        :meth:`tower_down` to chain onto the affected futures."""
+        accounting. Returns False when the lane gave up — breaker open,
+        retries exhausted, non-retryable error, or drain timeout — with
+        the terminal exception stashed for :meth:`tower_down` to chain
+        onto the affected futures."""
         eng = self.eng
         if eng._breaker.blocked():
             self._tower_exc = TowerFailure(
@@ -695,17 +775,47 @@ class _SlotPool:
                 f"({eng._breaker.failures} consecutive failures)")
             if overlap:
                 self._overlap_prepare()
-            return None
-        fut = eng._tower_submit(("drain", ids))
+            return False
+        item = ("drain", ids, wave)
+        fut = eng._tower_submit(item)
         if overlap:
             self._overlap_prepare()
         try:
-            return eng._tower_result(fut, ("drain", ids), pool=self)
+            with eng._span("serve.drain_wait", wave=wave):
+                eng._tower_result(fut, item, pool=self)
+            return True
         except (KeyboardInterrupt, SystemExit):
             raise
         except BaseException as exc:
             self._tower_exc = exc
-            return None
+            return False
+
+    def _finish_wave(self, wave: int, safe, keep, safe_np: np.ndarray,
+                     keep_np: np.ndarray, *, overlap: bool) -> bool:
+        """A planned wave's tail: charge its document lookups, drain the
+        fresh ones through the tower lane (staging the next admission
+        group meanwhile when ``overlap``), gather the wave's embeddings
+        from the host cache, score and commit on device. Returns False
+        when the tower lane gave up — :meth:`tower_down` has then resolved
+        the residents and the wave is not committed."""
+        eng = self.eng
+        lookups = safe_np[keep_np]
+        with eng._mu:
+            eng._counters.doc_lookups += lookups.size
+        if not self._drain_wave(lookups, wave, overlap=overlap):
+            self.tower_down()
+            return False
+        with eng._span("serve.gather", wave=wave):
+            doc = jnp.asarray(eng._doc_embs(safe_np, self.q_D.shape[1]))
+        with eng._span("serve.commit", wave=wave):
+            dists = _wave_dists_j(doc, jnp.asarray(self.q_D))
+            if eng._stepper is not None:
+                self.state = eng._stepper.commit(self.state, safe, keep,
+                                                 dists)
+            else:
+                self.state = _commit_j(self.state, safe, keep, dists,
+                                       backend=eng.backend)
+        return True
 
     def step(self) -> None:
         """One plan/drain/commit wave over every occupied slot. While the
@@ -718,31 +828,26 @@ class _SlotPool:
         eng = self.eng
         if eng.index_kind == "covertree":
             return self.step_ct()
-        self.ew_cap = max(self.ew_cap, int(self.ew.max()))
-        quota_j = jnp.asarray(self.quota)
-        L_j = jnp.asarray(self.L)
-        ms_j = jnp.asarray(self.ms)
-        if eng._stepper is not None:
-            self.state, safe, keep, _ = eng._stepper.plan(
-                self.state, eng._adjacency, quota_j, L_j, ms_j,
-                expand_width=jnp.asarray(self.ew), expand_cap=self.ew_cap)
-        else:
-            self.state, safe, keep, _ = _plan_step_j(
-                self.state, eng._adjacency, quota_j, L_j, ms_j,
-                jnp.asarray(self.ew), expand_cap=self.ew_cap)
-        safe_np = np.asarray(safe)
-        batches = self._drain_wave(safe_np[np.asarray(keep)], overlap=True)
-        if batches is None:
-            return self.tower_down()
-        self.tower_total += batches
-        doc = jnp.asarray(eng._doc_embs(safe_np, self.q_D.shape[1]))
-        dists = _wave_dists_j(doc, jnp.asarray(self.q_D))
-        if eng._stepper is not None:
-            self.state = eng._stepper.commit(self.state, safe, keep, dists)
-        else:
-            self.state = _commit_j(self.state, safe, keep, dists,
-                                   backend=eng.backend)
-        self.sweep_early()
+        wave = self._next_wave()
+        with eng._span("serve.wave", wave=wave, entry=0):
+            self.ew_cap = max(self.ew_cap, int(self.ew.max()))
+            with eng._span("serve.plan", wave=wave):
+                quota_j = jnp.asarray(self.quota)
+                L_j = jnp.asarray(self.L)
+                ms_j = jnp.asarray(self.ms)
+                if eng._stepper is not None:
+                    self.state, safe, keep, _ = eng._stepper.plan(
+                        self.state, eng._adjacency, quota_j, L_j, ms_j,
+                        expand_width=jnp.asarray(self.ew),
+                        expand_cap=self.ew_cap)
+                else:
+                    self.state, safe, keep, _ = _plan_step_j(
+                        self.state, eng._adjacency, quota_j, L_j, ms_j,
+                        jnp.asarray(self.ew), expand_cap=self.ew_cap)
+                safe_np, keep_np = np.asarray(safe), np.asarray(keep)
+            if self._finish_wave(wave, safe, keep, safe_np, keep_np,
+                                 overlap=True):
+                self.sweep_early()
 
     def step_ct(self) -> None:
         """One cover-tree level for every slot still descending.
@@ -764,12 +869,54 @@ class _SlotPool:
         if l1 == 0:
             self.ms[stepping] = 0
             return
+        wave = self._next_wave()
+        with eng._span("serve.wave", wave=wave, entry=0):
+            with eng._span("serve.plan", wave=wave):
+                planned = self._plan_ct_level(stepping, chunk, l1)
+            for i, (safe, keep, safe_np, keep_np) in enumerate(planned):
+                if not self._finish_wave(wave, safe, keep, safe_np, keep_np,
+                                         overlap=(i == 0)):
+                    return
+            pd0 = np.asarray(self.state.pool_dists[:, 0], np.float64)
+            cont = np.zeros(self.S, bool)
+            t = self.ct_level.copy()
+            for s in np.nonzero(stepping)[0]:
+                tt = int(t[s])
+                if tt >= l1:
+                    self.ms[s] = 0
+                    continue
+                self.ct_level[s] = tt + 1
+                stop = not (pd0[s] < radii[tt] * (1.0 + 1.0 / eng.ct_eps))
+                if stop or tt + 1 >= l1:
+                    self.ms[s] = 0
+                else:
+                    cont[s] = True
+            # rows still descending keep an open frontier so active_mask
+            # holds them resident even when a level admitted nothing fresh
+            # (the next level's child rows may still reach new points).
+            # Rows resolved early mid-level (deadline) stay frozen.
+            cont &= ~self.early
+            if cont.any():
+                if eng._stepper is not None:
+                    self.state = eng._stepper.reopen(self.state,
+                                                     jnp.asarray(cont))
+                else:
+                    self.state = _reopen_j(self.state, jnp.asarray(cont))
+            self.sweep_early()
+
+    def _plan_ct_level(self, stepping: np.ndarray, chunk: int,
+                       l1: int) -> list:
+        """Size each stepping row's frontier at its level, re-open it and
+        plan the level's chunk-wide waves; returns each wave's
+        ``(safe, keep)`` on device and on the host."""
+        eng = self.eng
         quota_j = jnp.asarray(self.quota)
         L_j = jnp.asarray(self.L)
         ms_j = jnp.asarray(self.ms)
-        t = self.ct_level.copy()
+        t = self.ct_level
         radius = np.where(t == 0, np.inf,
-                          radii[np.maximum(t - 1, 0)]).astype(np.float32)
+                          eng._ct_radii[np.maximum(t - 1, 0)]
+                          ).astype(np.float32)
         ew_t = np.asarray(_frontier_j(self.state.pool_dists,
                                       jnp.asarray(radius)))
         ew_t = np.where(stepping, ew_t, 0).astype(np.int32)
@@ -794,67 +941,8 @@ class _SlotPool:
                     jnp.asarray(ew), expand_cap=chunk)
             planned.append((safe, keep))
             remaining -= ew
-        for i, (safe, keep) in enumerate(planned):
-            safe_np = np.asarray(safe)
-            batches = self._drain_wave(safe_np[np.asarray(keep)],
-                                       overlap=(i == 0))
-            if batches is None:
-                return self.tower_down()
-            self.tower_total += batches
-            doc = jnp.asarray(eng._doc_embs(safe_np, self.q_D.shape[1]))
-            dists = _wave_dists_j(doc, jnp.asarray(self.q_D))
-            if eng._stepper is not None:
-                self.state = eng._stepper.commit(self.state, safe, keep,
-                                                 dists)
-            else:
-                self.state = _commit_j(self.state, safe, keep, dists,
-                                       backend=eng.backend)
-        pd0 = np.asarray(self.state.pool_dists[:, 0], np.float64)
-        cont = np.zeros(self.S, bool)
-        for s in np.nonzero(stepping)[0]:
-            tt = int(t[s])
-            if tt >= l1:
-                self.ms[s] = 0
-                continue
-            self.ct_level[s] = tt + 1
-            stop = not (pd0[s] < radii[tt] * (1.0 + 1.0 / eng.ct_eps))
-            if stop or tt + 1 >= l1:
-                self.ms[s] = 0
-            else:
-                cont[s] = True
-        # rows still descending keep an open frontier so active_mask holds
-        # them resident even when a level admitted nothing fresh (the next
-        # level's child rows may still reach new points). Rows resolved
-        # early mid-level (deadline) stay frozen.
-        cont &= ~self.early
-        if cont.any():
-            if eng._stepper is not None:
-                self.state = eng._stepper.reopen(self.state,
-                                                 jnp.asarray(cont))
-            else:
-                self.state = _reopen_j(self.state, jnp.asarray(cont))
-        self.sweep_early()
-
-    def _drain_and_commit(self, safe, keep) -> bool:
-        """Entry-wave drain + commit (same tower lane as the step drains).
-        Returns False when the tower lane gave up — the caller's group is
-        already resolved/failed by :meth:`tower_down`."""
-        eng = self.eng
-        safe_np = np.asarray(safe)
-        batches = self._drain_wave(safe_np[np.asarray(keep)], overlap=False)
-        if batches is None:
-            self.tower_down()
-            return False
-        self.tower_total += batches
-        doc = jnp.asarray(eng._doc_embs(safe_np, self.q_D.shape[1]))
-        dists = _wave_dists_j(doc, jnp.asarray(self.q_D))
-        if eng._stepper is not None:
-            self.state = eng._stepper.commit(self.state, safe, keep, dists)
-        else:
-            self.state = _commit_j(self.state, safe, keep, dists,
-                                   backend=eng.backend)
-        self.sweep_early()
-        return True
+        return [(safe, keep, np.asarray(safe), np.asarray(keep))
+                for safe, keep in planned]
 
     # ------------------------------------------------- degradation/deadlines
     def has_deadlines(self) -> bool:
@@ -889,7 +977,6 @@ class _SlotPool:
         ok = (ids >= 0) & np.isfinite(dd)
         stats = ServeStats(
             d_calls=a.d_calls, D_calls=D_calls,
-            tower_batches=self.tower_total - a.tower0,
             queue_ms=(a.t_admit - a.pend.t_submit) * 1e3,
             compute_ms=(now - a.t_admit) * 1e3,
             slot_occupancy=a.occ_snap, queue_depth=a.depth_snap,
@@ -1016,36 +1103,36 @@ class _SlotPool:
         fin = self.occupied & ~act & ~self.early
         if not fin.any():
             return
-        ids_all = np.asarray(self.state.pool_ids)
-        dd_all = np.asarray(self.state.pool_dists)
-        calls = np.asarray(self.state.n_calls)
-        now = time.monotonic()
-        done = 0
-        misses = 0
-        for s in np.nonzero(fin)[0]:
-            a = self.active_req[s]
-            r = a.pend.req
-            kk = int(r.k)
-            row_ids = ids_all[s, :kk].astype(np.int64)
-            row_dd = dd_all[s, :kk].astype(np.float64)
-            ok = (row_ids >= 0) & np.isfinite(row_dd)
-            stats = ServeStats(
-                d_calls=a.d_calls, D_calls=int(calls[s]),
-                tower_batches=self.tower_total - a.tower0,
-                queue_ms=(a.t_admit - a.pend.t_submit) * 1e3,
-                compute_ms=(now - a.t_admit) * 1e3,
-                slot_occupancy=a.occ_snap, queue_depth=a.depth_snap)
-            if (r.deadline_ms is not None
-                    and (now - a.pend.t_submit) * 1e3 > r.deadline_ms):
-                misses += 1  # admitted late: resolve anyway, count the miss
-            a.pend.future._resolve(
-                SearchResult(row_ids[ok], row_dd[ok], stats))
-            done += 1
-            self.free_slot(s)
-        with eng._mu:
-            eng._counters.completed += done
-            eng._counters.deadline_misses += misses
-            eng._counters.slot_occupancy = int(self.occupied.sum())
+        with eng._span("serve.resolve", resolved=int(fin.sum())):
+            ids_all = np.asarray(self.state.pool_ids)
+            dd_all = np.asarray(self.state.pool_dists)
+            calls = np.asarray(self.state.n_calls)
+            now = time.monotonic()
+            done = 0
+            misses = 0
+            for s in np.nonzero(fin)[0]:
+                a = self.active_req[s]
+                r = a.pend.req
+                kk = int(r.k)
+                row_ids = ids_all[s, :kk].astype(np.int64)
+                row_dd = dd_all[s, :kk].astype(np.float64)
+                ok = (row_ids >= 0) & np.isfinite(row_dd)
+                stats = ServeStats(
+                    d_calls=a.d_calls, D_calls=int(calls[s]),
+                    queue_ms=(a.t_admit - a.pend.t_submit) * 1e3,
+                    compute_ms=(now - a.t_admit) * 1e3,
+                    slot_occupancy=a.occ_snap, queue_depth=a.depth_snap)
+                if (r.deadline_ms is not None
+                        and (now - a.pend.t_submit) * 1e3 > r.deadline_ms):
+                    misses += 1  # admitted late: resolve, count the miss
+                a.pend.future._resolve(
+                    SearchResult(row_ids[ok], row_dd[ok], stats))
+                done += 1
+                self.free_slot(s)
+            with eng._mu:
+                eng._counters.completed += done
+                eng._counters.deadline_misses += misses
+                eng._counters.slot_occupancy = int(self.occupied.sum())
 
     def free_slot(self, s: int) -> None:
         self.occupied[s] = False
@@ -1281,23 +1368,27 @@ class BiMetricEngine:
             n_points=self.n, beam_width=width, pool_size=pool,
             max_steps=max_steps, backend=self.backend)
 
-    def _drain_tower(self, ids: np.ndarray) -> int:
-        """Embed not-yet-cached docs through the expensive tower; returns the
-        number of forward batches drained. Serialized by the cache lock (the
-        tower lane is single-file by construction; the lock also covers
-        synchronous callers running concurrently with the slot drive)."""
+    def _drain_tower(self, ids: np.ndarray) -> None:
+        """Embed not-yet-cached docs through the expensive tower, counting
+        the rows and forward batches drained. Serialized by the cache lock
+        (the tower lane is single-file by construction; the lock also
+        covers synchronous callers running concurrently with the slot
+        drive)."""
         with self._cache_lock:
             need = np.unique(
                 ids[(ids >= 0) & ~self._emb_D_valid[np.maximum(ids, 0)]])
             if need.size == 0:
-                return 0
+                return
             embs = self.expensive.embed(self.corpus_tokens[need],
                                         batch=self.tower_batch)
             if self._emb_D is None:
                 self._emb_D = np.zeros((self.n, embs.shape[1]), embs.dtype)
             self._emb_D[need] = embs
             self._emb_D_valid[need] = True
-            return -(-need.size // self.tower_batch)
+            with self._mu:
+                self._counters.drained_rows += need.size
+                self._counters.drain_batches += -(-need.size
+                                                  // self.tower_batch)
 
     def reset_doc_cache(self) -> None:
         """Drop the expensive-tower document cache (benchmark hygiene)."""
@@ -1333,7 +1424,7 @@ class BiMetricEngine:
 
         Yields tower-lane work items — ``("embed_queries", tokens)`` then one
         ``("drain", ids)`` per stage-2 wave — and receives the answer via
-        ``send`` (the expensive query embeddings / the drained batch count).
+        ``send`` (the expensive query embeddings / nothing for a drain).
         Device-lane work (cheap embed, stage 1, plan/commit bookkeeping)
         runs between yields. Returns ``(ids, dists, stats)`` via
         ``StopIteration.value``. The async slot drive runs the identical
@@ -1381,7 +1472,6 @@ class BiMetricEngine:
         L_j = jnp.asarray(L)
         ms_j = jnp.asarray(max_steps)
         ew_j = jnp.asarray(ew_np)
-        tower_batches = 0
 
         # dedup backend for the wave (host-driven drive: the non-donated
         # bitmap would be copied through every dispatch, so auto favors the
@@ -1402,7 +1492,7 @@ class BiMetricEngine:
                 set_capacity=cap)
         while True:
             safe_np = np.asarray(safe)
-            tower_batches += yield ("drain", safe_np[np.asarray(keep)])
+            yield ("drain", safe_np[np.asarray(keep)])
             doc_embs = jnp.asarray(self._doc_embs(safe_np, q_D.shape[1]))
             dists = _wave_dists_j(doc_embs, q_D)
             if stepper is not None:
@@ -1425,8 +1515,8 @@ class BiMetricEngine:
         ids = np.asarray(state.pool_ids[:, :kmax], np.int64)
         dd = np.asarray(state.pool_dists[:, :kmax], np.float64)
         D_calls = np.asarray(state.n_calls)
-        stats = [ServeStats(d_calls=int(d_calls[i]), D_calls=int(D_calls[i]),
-                            tower_batches=tower_batches) for i in range(b)]
+        stats = [ServeStats(d_calls=int(d_calls[i]), D_calls=int(D_calls[i]))
+                 for i in range(b)]
         return ids, dd, stats
 
     def _wave_gen_ct(self, query_tokens: np.ndarray, quota, k):
@@ -1470,13 +1560,10 @@ class BiMetricEngine:
             state, safe, keep = _init_j(
                 entries, quota_j, n_points=self.n, pool_size=P,
                 dedup=dedup, set_capacity=cap)
-        tower_batches = 0
 
         def _commit(s, sf, kp):
-            nonlocal tower_batches
             safe_np = np.asarray(sf)
-            batches = yield ("drain", safe_np[np.asarray(kp)])
-            tower_batches += batches
+            yield ("drain", safe_np[np.asarray(kp)])
             doc = jnp.asarray(self._doc_embs(safe_np, q_D.shape[1]))
             dists = _wave_dists_j(doc, q_D)
             if stepper is not None:
@@ -1521,13 +1608,15 @@ class BiMetricEngine:
         ids = np.asarray(state.pool_ids[:, :kmax], np.int64)
         dd = np.asarray(state.pool_dists[:, :kmax], np.float64)
         D_calls = np.asarray(state.n_calls)
-        stats = [ServeStats(d_calls=0, D_calls=int(D_calls[i]),
-                            tower_batches=tower_batches) for i in range(b)]
+        stats = [ServeStats(d_calls=0, D_calls=int(D_calls[i]))
+                 for i in range(b)]
         return ids, dd, stats
 
     def _service_tower(self, item):
-        """Run one tower-lane work item (the expensive-tower forward passes)."""
-        kind, payload = item
+        """Run one tower-lane work item (the expensive-tower forward passes):
+        ``(kind, payload)``, and on the slot drive a third field, the id of
+        the group or wave that asked for it."""
+        kind, payload = item[:2]
         if self._faults is not None:
             # injection precedes the real work (and the doc-cache write), so
             # a retried drain recomputes from the same cache state — retries
@@ -1649,13 +1738,40 @@ class BiMetricEngine:
         return fut
 
     def counters(self) -> EngineCounters:
-        """Snapshot of the admission-layer counters (cumulative since
-        engine construction; ``queue_depth`` / ``slot_occupancy`` are
-        instantaneous)."""
+        """Snapshot of the serving counters (cumulative since engine
+        construction; ``queue_depth`` / ``slot_occupancy`` are
+        instantaneous), with the towers' row counts read in."""
         with self._mu:
-            snap = dataclasses.replace(self._counters)
+            c = self._counters
+            snap = dataclasses.replace(c, span_n=dict(c.span_n),
+                                       span_s=dict(c.span_s))
         snap.breaker_opens = self._breaker.opens
+        for label in ("cheap", "expensive"):
+            # a tower is anything with ``embed``; only an EmbedTower counts
+            row_counts = getattr(getattr(self, label), "row_counts", None)
+            if row_counts is not None:
+                rows, useful = row_counts()
+                setattr(snap, f"{label}_rows", rows)
+                setattr(snap, f"{label}_rows_useful", useful)
         return snap
+
+    @contextlib.contextmanager
+    def _span(self, name: str, **ids):
+        """One ``serve.*`` span: a profiler host span (``jax.profiler.
+        TraceAnnotation``, on the device trace's clock, ``ids`` as its
+        stats) and, as it closes, one more ``span_n[name]`` and its host
+        seconds on ``span_s[name]`` in :class:`EngineCounters` — the
+        record when no profiler runs. Adds no host/device sync."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **ids):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            with self._mu:
+                n, sec = self._counters.span_n, self._counters.span_s
+                n[name] = n.get(name, 0) + 1
+                sec[name] = sec.get(name, 0.0) + dt
 
     def health(self) -> dict:
         """Operational snapshot: breaker state, degradation mode, queue and
@@ -1897,8 +2013,15 @@ class BiMetricEngine:
             if got is _STOP:
                 break
             item, fut = got
+            kind, payload, tag = item
+            span = (self._span("serve.tower.drain", wave=tag,
+                               rows=int(payload.size))
+                    if kind == "drain"
+                    else self._span("serve.tower.query_embed", group=tag))
             try:
-                fut.set_result(self._service_tower(item))
+                with span:
+                    out = self._service_tower(item)
+                fut.set_result(out)
             except (KeyboardInterrupt, SystemExit) as exc:
                 fut.set_exception(exc)  # surface on drive, then honor it
                 raise
@@ -1929,7 +2052,7 @@ class BiMetricEngine:
         res1 = self._stage1(q_d, width=width, pool=max(width, quota),
                             max_steps=8 * width)
         cand = np.asarray(res1.pool_ids[:, :quota])
-        tower_batches = self._drain_tower(cand)
+        self._drain_tower(cand)
         doc_embs = self._emb_D[np.maximum(cand, 0)]  # host-side, no transfer
         diff = doc_embs - np.asarray(q_D)[:, None, :]
         dd = np.sqrt((diff * diff).sum(-1))
@@ -1937,8 +2060,8 @@ class BiMetricEngine:
         order = np.argsort(dd, axis=1, kind="stable")[:, :k]
         d_calls = np.asarray(res1.n_calls)
         n_D = (cand >= 0).sum(1)
-        stats = [ServeStats(d_calls=int(d_calls[i]), D_calls=int(n_D[i]),
-                            tower_batches=tower_batches) for i in range(b)]
+        stats = [ServeStats(d_calls=int(d_calls[i]), D_calls=int(n_D[i]))
+                 for i in range(b)]
         return (np.take_along_axis(cand, order, 1).astype(np.int64),
                 np.take_along_axis(dd, order, 1), stats)
 

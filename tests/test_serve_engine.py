@@ -54,19 +54,24 @@ def test_quota_exact_and_batch_single_parity(engine_parts):
 def test_cache_saves_tower_batches_not_accounting(engine_parts):
     eng = _fresh_engine(engine_parts)
     q = eng.corpus_tokens[7]
+    b0 = eng.counters().drain_batches
     ids1, dd1, s1 = eng.query(q, quota=12, k=5)
+    b1 = eng.counters().drain_batches
     ids2, dd2, s2 = eng.query(q, quota=12, k=5)
+    b2 = eng.counters().drain_batches
     assert (ids1 == ids2).all()
     np.testing.assert_array_equal(dd1, dd2)
     assert s1.D_calls == s2.D_calls  # budget accounting is cache-blind
-    assert s2.tower_batches == 0  # but the tower is not re-run
-    assert s1.tower_batches > 0
+    assert b2 - b1 == 0  # but the tower is not re-run
+    assert b1 - b0 > 0
 
 
 def test_quota_zero_spends_nothing(engine_parts):
     eng = _fresh_engine(engine_parts)
+    b0 = eng.counters().drain_batches
     ids, dd, st = eng.query(eng.corpus_tokens[0], quota=0, k=5)
-    assert ids.size == 0 and st.D_calls == 0 and st.tower_batches == 0
+    assert ids.size == 0 and st.D_calls == 0
+    assert eng.counters().drain_batches - b0 == 0
 
 
 def test_rerank_exact_budget(engine_parts):
