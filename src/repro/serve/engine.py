@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import kernels
-from repro.core import beam, covertree, distances, vamana
+from repro.core import beam, covertree, vamana
 from repro.distributed import sharding
 from repro.kernels import ops
 from repro.models import transformer as T
@@ -231,6 +231,9 @@ class EngineCounters:
     cheap_rows_useful: int = 0
     expensive_rows: int = 0
     expensive_rows_useful: int = 0
+    # traces of the stage-1 program this engine's calls set off (one per
+    # (batch, pool size) bucket that no engine in the process traced first)
+    stage1_traces: int = 0
     # per ``serve.*`` span name: spans closed, and their host seconds
     span_n: dict = dataclasses.field(default_factory=dict)
     span_s: dict = dataclasses.field(default_factory=dict)
@@ -342,13 +345,42 @@ class _Prepared:
 
 
 _STOP = object()  # tower-queue sentinel
+_METRIC_D = "l2"  # the cheap metric d over the cheap tower's embeddings
 
 
 # ---------------------------------------------------------------------------
-# jitted device-lane steps (shards == 1). beam_width / max_steps / quota /
-# expand_width ride as (B,) operands so mixed per-query budgets do not
-# retrace; only the static lane cap (expand_cap) recompiles.
+# jitted device-lane steps (shards == 1), stage 1 (_stage1_j) among them.
+# beam_width / max_steps / quota / expand_width ride as (B,) operands so
+# mixed per-query budgets do not retrace; only the static caps (pool_size,
+# expand_cap) recompile. The corpus and the graph are operands, never
+# constants baked into a program, so engines over same-shaped corpora share
+# one executable per shape.
 # ---------------------------------------------------------------------------
+_stage1_caller = threading.local()  # .engine: whose call is tracing
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_points", "pool_size", "metric", "backend"))
+def _stage1_j(corpus, adjacency, q_d, entries, width, max_steps, *,
+              n_points, pool_size, metric, backend):
+    """Stage 1 as one program: the cheap-metric batched greedy search over
+    ``corpus`` (a :class:`repro.kernels.CorpusView`, or the raw embeddings
+    on the ``ref`` route) and ``adjacency``, scored by the same
+    ``ops.gather_score`` as :func:`repro.core.beam.fused_dist_fn`.
+    ``width`` / ``max_steps`` are (B,) int32; every caller passes
+    ``pool_size >= max(width)``. This body runs only while JAX traces it,
+    so it counts the trace (``EngineCounters.stage1_traces``) on the engine
+    whose call set it off."""
+    eng = getattr(_stage1_caller, "engine", None)
+    if eng is not None:
+        with eng._mu:
+            eng._counters.stage1_traces += 1
+    return beam.batched_greedy_search(
+        beam.fused_dist_fn(corpus, metric, backend=backend), adjacency,
+        q_d, entries, n_points=n_points, beam_width=width,
+        pool_size=pool_size, max_steps=max_steps, backend=backend)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "n_points", "pool_size", "dedup", "set_capacity"))
 def _init_j(entry_ids, quota, *, n_points, pool_size, dedup, set_capacity):
@@ -1290,9 +1322,7 @@ class BiMetricEngine:
             self._ct_radii = np.asarray(self._flat.radii, np.float64)
             self._ct_chunk = covertree.wave_chunk(self._flat.fanout)
             self.index = None
-            self._em_d = None
-            self._view_d = None
-            self._dist_d = None
+            self._corpus_d = None
             self._adjacency = None
         else:
             self._flat = None
@@ -1300,22 +1330,16 @@ class BiMetricEngine:
                                       index_cfg or vamana.VamanaConfig(
                                           max_degree=16, l_build=24,
                                           pool_size=48, rev_candidates=16))
-            self._em_d = distances.EmbeddingMetric(self.emb_d)
-            # stage-1 scoring route: the matmul backends thread the
-            # corpus-norm cache (built ONCE here, like the index) through
-            # every wave; with quantize= the view is built quantized, also
-            # once — the graph is still built on the exact embeddings, only
-            # wave scoring is lossy
+            # what stage 1 scores (an operand of _stage1_j): the matmul
+            # backends thread the corpus-norm cache (built ONCE here, like
+            # the index) through every wave; with quantize= the view is
+            # built quantized, also once — the graph is still built on the
+            # exact embeddings, only wave scoring is lossy
             need_view = (self.backend.matmul
                          or self.backend.quantize is not None)
-            self._view_d = (kernels.as_corpus_view(
+            self._corpus_d = (kernels.as_corpus_view(
                 self.emb_d, quantize=self.backend.quantize)
-                if need_view else None)
-            if need_view and shards == 1:
-                self._dist_d = beam.fused_dist_fn(
-                    self._view_d, self._em_d.metric, backend=self.backend)
-            else:
-                self._dist_d = self._em_d.dists_batch
+                if need_view else self.emb_d)
             self._adjacency = self.index.adjacency.astype(jnp.int32)
         # one mesh for the engine lifetime; stage 2 steps through the same
         # mesh as stage 1 (ShardedStepper = the in-mesh plan/commit programs)
@@ -1349,24 +1373,33 @@ class BiMetricEngine:
                 max_steps) -> beam.SearchResult:
         """Batched cheap-metric greedy search from the medoid (stage 1).
 
-        ``width`` / ``max_steps`` may be per-query (B,) vectors (request
-        waves mix budgets); ``pool`` is the static pool size. With
-        ``shards > 1`` the same loop runs device-parallel over the engine's
-        corpus mesh — bit-exact vs the single-device path."""
+        ``width`` / ``max_steps`` are scalars or per-query (B,) vectors
+        (request waves mix budgets), passed on as (B,) int32 operands;
+        ``pool`` (>= max(width)) is the static pool size. On one device
+        this is :func:`_stage1_j`, one of the jitted device-lane steps, with
+        the corpus and graph as operands: traced and compiled once per
+        (B, pool) bucket, never again at an admission. With ``shards > 1``
+        the same loop runs device-parallel over the engine's corpus mesh —
+        bit-exact vs the single-device path."""
         b = q_d.shape[0]
         entries = jnp.broadcast_to(
             jnp.asarray(self.index.medoid, jnp.int32).reshape(1, 1), (b, 1))
         if self.shards > 1:
             return beam.sharded_greedy_search(
-                self._view_d if self._view_d is not None else self.emb_d,
-                self._adjacency, q_d, entries,
-                shards=self.shards, metric=self._em_d.metric,
+                self._corpus_d, self._adjacency, q_d, entries,
+                shards=self.shards, metric=_METRIC_D,
                 mesh=self._mesh, beam_width=width, pool_size=pool,
                 max_steps=max_steps, backend=self.backend)
-        return beam.batched_greedy_search(
-            self._dist_d, self._adjacency, q_d, entries,
-            n_points=self.n, beam_width=width, pool_size=pool,
-            max_steps=max_steps, backend=self.backend)
+        _stage1_caller.engine = self
+        try:
+            return _stage1_j(
+                self._corpus_d, self._adjacency, q_d, entries,
+                jnp.broadcast_to(jnp.asarray(width, jnp.int32), (b,)),
+                jnp.broadcast_to(jnp.asarray(max_steps, jnp.int32), (b,)),
+                n_points=self.n, pool_size=int(pool), metric=_METRIC_D,
+                backend=self.backend)
+        finally:
+            _stage1_caller.engine = None
 
     def _drain_tower(self, ids: np.ndarray) -> None:
         """Embed not-yet-cached docs through the expensive tower, counting

@@ -6,12 +6,15 @@ build -> batched stage 1 on device -> host-driven stage 2 draining the
 expensive tower in batches.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import qwen3_0_6b
+from repro.core import beam
 from repro.models import transformer as T
-from repro.serve import BiMetricEngine, EmbedTower
+from repro.serve import BiMetricEngine, EmbedTower, SearchRequest
+from repro.serve.engine import _METRIC_D, _stage1_j
 
 
 @pytest.fixture(scope="module")
@@ -124,3 +127,70 @@ def test_dedup_capacity_rounding_bounds_retraces(engine_parts):
         ok = (ids_m[i] >= 0) & np.isfinite(dd_m[i])
         assert np.array_equal(ids1, ids_m[i][ok])
         assert s1.D_calls == st_m[i].D_calls
+
+
+def _serve_async(eng, reqs):
+    return [f.result(timeout=300) for f in [eng.submit(r) for r in reqs]]
+
+
+def test_stage1_traced_once_per_shape(engine_parts):
+    """Stage 1 is one jitted program per (batch, pool) bucket: a second
+    same-shape admission group adds no trace, and neither does a second
+    engine over another corpus of the same shape — the corpus and graph
+    are operands of the program, not constants baked into it. Both drives
+    still answer bit-identically."""
+    cheap, expensive, corpus = engine_parts
+    other = np.random.default_rng(1).integers(
+        0, cheap.cfg.vocab, corpus.shape, dtype=np.int32)
+    # five slots: a (batch, pool) bucket no other test of this file uses
+    eng = BiMetricEngine(cheap, expensive, corpus, max_batch=5,
+                         max_wait_ms=500.0)
+    eng2 = BiMetricEngine(cheap, expensive, other, max_batch=5,
+                          max_wait_ms=500.0)
+    groups = [[SearchRequest(tokens=c[i], quota=15, k=5) for i in rows]
+              for c, rows in ((corpus, (3, 40, 77)), (corpus, (11, 58, 90)),
+                              (other, (5, 6, 7)))]
+    try:
+        t0 = eng.counters().stage1_traces
+        first = _serve_async(eng, groups[0])
+        t1 = eng.counters().stage1_traces
+        second = _serve_async(eng, groups[1])
+        assert eng.counters().stage1_traces == t1
+        assert t1 - t0 <= 1
+        on_other = _serve_async(eng2, groups[2])
+        assert eng2.counters().stage1_traces == 0
+        assert eng.counters().stage1_traces == t1
+    finally:
+        eng.close()
+        eng2.close()
+    for e, reqs, got in ((eng, groups[0], first), (eng, groups[1], second),
+                         (eng2, groups[2], on_other)):
+        for a, b in zip(got, e.query_batch(reqs)):
+            assert np.array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.dists, b.dists)
+            assert a.stats.d_calls == b.stats.d_calls
+            assert a.stats.D_calls == b.stats.D_calls
+
+
+@pytest.mark.parametrize("backend", ["ref", "xla_matmul"])
+def test_stage1_program_matches_eager_search(engine_parts, backend):
+    """``_stage1_j`` answers what the eager ``batched_greedy_search`` over
+    the engine's corpus answers, on the plain and the matmul route: the
+    same pools, calls and steps, distances to float32 rounding."""
+    cheap, expensive, corpus = engine_parts
+    eng = BiMetricEngine(cheap, expensive, corpus, backend=backend)
+    q_d = jnp.asarray(cheap.embed(corpus[[3, 40, 77, 11]]))
+    entries = jnp.full((4, 1), eng.index.medoid, jnp.int32)
+    width = jnp.asarray([32, 1, 40, 36], jnp.int32)
+    max_steps = jnp.asarray([128, 0, 160, 144], jnp.int32)
+    got = _stage1_j(eng._corpus_d, eng._adjacency, q_d, entries, width,
+                    max_steps, n_points=eng.n, pool_size=64,
+                    metric=_METRIC_D, backend=eng.backend)
+    want = beam.batched_greedy_search(
+        beam.fused_dist_fn(eng._corpus_d, _METRIC_D, backend=eng.backend),
+        eng._adjacency, q_d, entries, n_points=eng.n, beam_width=width,
+        pool_size=64, max_steps=max_steps, backend=eng.backend)
+    for field in ("pool_ids", "n_calls", "n_steps", "scored"):
+        np.testing.assert_array_equal(getattr(got, field),
+                                      getattr(want, field), err_msg=field)
+    np.testing.assert_allclose(got.pool_dists, want.pool_dists, rtol=1e-6)

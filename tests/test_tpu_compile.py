@@ -98,3 +98,23 @@ def test_flash_attention_compiles(spec):
     text = _compiled_text(
         lambda q, k, v: fa.flash_attention(q, k, v, causal=True), s, s, s)
     assert "tpu_custom_call" in text
+
+
+def test_stage1_program_compiles(spec):
+    """The serving engine's stage-1 program on the Pallas route, at the
+    SCIDOCS-shaped corpus (25,657 rows of d384, degree 16), with the corpus
+    view and the graph as arguments: one while loop holding the kernels."""
+    from repro.kernels import CorpusView
+    from repro.kernels.backend import Backend
+    from repro.serve.engine import _METRIC_D, _stage1_j
+
+    n, dim = 25657, 384
+    view = CorpusView(rows=spec((n, dim), jnp.float32),
+                      sq_norms=spec((n,), jnp.float32),
+                      inv_norms=spec((n,), jnp.float32))
+    text = _stage1_j.lower(
+        view, spec((n, FANOUT), jnp.int32), spec((SLOTS, dim), jnp.float32),
+        spec((SLOTS, 1), jnp.int32), spec((SLOTS,), jnp.int32),
+        spec((SLOTS,), jnp.int32), n_points=n, pool_size=64,
+        metric=_METRIC_D, backend=Backend("pallas")).compile().as_text()
+    assert "tpu_custom_call" in text and "while" in text
