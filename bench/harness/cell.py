@@ -207,9 +207,10 @@ class Built:
     phases: dict
 
 
-def build(cfg: dict, mix: dict, seed: int,
+def build(cfg: dict, mix: dict, seed: int, bench: Path,
           counter: CompileCounter | None = None) -> Built:
-    """Set-up: weights, data, the engine's index build, the warm-up."""
+    """Set-up: weights, data, the engine's index build, the warm-up. Each
+    tower's module is found under ``bench``."""
     from repro.core.vamana import VamanaConfig
     from repro.serve import BiMetricEngine, SearchRequest
 
@@ -225,12 +226,15 @@ def build(cfg: dict, mix: dict, seed: int,
         t = now
 
     key = model.seed_key(seed)
-    sizes = {k: model.tower_dict(cfg, k) for k in ("cheap", "expensive")}
+    sizes = {k: model.tower_dict(cfg, k, bench)
+             for k in ("cheap", "expensive")}
     params = {k: model.make_params(jax.random.fold_in(key, i), sizes[k])
               for i, k in enumerate(("cheap", "expensive"))}
     phase("weights")
+    # both towers read the same token ids: draw them from the smaller table
+    vocab = min(int(p["embed"].shape[0]) for p in params.values())
     data = data_mod.make(seed, cfg, int(mix["warmup_requests"]),
-                         int(mix["max_requests"]))
+                         int(mix["max_requests"]), vocab)
     reqs = [SearchRequest(tokens=q, quota=int(mix["quota"]), k=int(mix["k"]))
             for q in data.queries]
     docs = {row.tobytes() for row in data.corpus}
@@ -269,7 +273,7 @@ def run_cell(spec, name: str, seed: int, seconds: float, traced: bool, *,
     dev = jax.devices()[0]
     counter = CompileCounter()
     counter.on = True
-    b = build(cfg, mix, seed, counter)
+    b = build(cfg, mix, seed, spec.bench, counter)
     if patch is not None:
         patch(b.eng)
     tracer = None

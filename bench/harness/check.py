@@ -34,25 +34,20 @@ The last three are exact: their limit is 0.
 """
 from __future__ import annotations
 
-import importlib.util
-import sys
 from pathlib import Path
 
 import numpy as np
+
+from harness import spec
 
 EXACT = ("unanswered", "over_quota", "bad_rows")
 
 
 def load_reference(cfg: dict, bench_dir: Path):
-    path = bench_dir / "configs" / f"{cfg['reference']}.py"
-    name = f"_bench_ref_{cfg['reference']}"
-    if name in sys.modules:
-        return sys.modules[name]
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    sys.modules[name] = mod
-    return mod
+    """The configuration's plain reference, ``embed(params, tokens, t,
+    precision=...)``; ``t`` is a tower's sizes, its module's name among
+    them (``harness.model.tower_dict``)."""
+    return spec.config_module(bench_dir, cfg["reference"])
 
 
 def exact_counts(done: list, n_failed: int, *, quota: int, k: int,

@@ -1,9 +1,10 @@
 """The corpus and the queries, made from the run's seed.
 
-Tokens are drawn from 1..vocab-1, so token 0 never appears and an all-zero
-row is always padding. Each query is a corpus document (its "planted"
-neighbour) with the first half of its tokens replaced, so every query has
-a near neighbour in the corpus. Source documents are drawn without
+Tokens are drawn from 1..vocab-1 (``vocab`` the smaller of the two
+towers' tables), so token 0 never appears and an all-zero row is always
+padding. Each query is a corpus document (its "planted" neighbour) with
+the first half of its tokens replaced, so every query has a near
+neighbour in the corpus. Source documents are drawn without
 replacement: no two queries share one, and warm-up queries are drawn apart
 from the window's.
 """
@@ -22,8 +23,9 @@ class Data:
     n_warmup: int
 
 
-def make(seed: int, cfg: dict, n_warmup: int, n_window: int) -> Data:
-    n_docs, doc_len, vocab = cfg["n_docs"], cfg["doc_len"], cfg["vocab"]
+def make(seed: int, cfg: dict, n_warmup: int, n_window: int,
+         vocab: int) -> Data:
+    n_docs, doc_len = cfg["n_docs"], cfg["doc_len"]
     if cfg["query_len"] != doc_len:
         raise ValueError("query rows must be as long as document rows")
     rng = np.random.default_rng([seed, 0])

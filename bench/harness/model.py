@@ -2,31 +2,55 @@
 
 A configuration file (``bench/configs/<config>.json``) states the expensive
 tower D at its top level and the cheap tower d as the nested group
-``cheap_tower``. Weights are drawn here, by the benchmark, on the device in
-one jitted call per tower, in the dtype the tower is served in: the program
-under test receives them as inputs, and the plain reference reads the same
-arrays, so neither side makes weights for the other.
+``cheap_tower``. Each group may name its architecture, ``"tower":
+"<module>"``: the file ``bench/configs/<module>.py``, found by name as the
+configuration's ``reference`` is (``harness.spec``). A group that names
+none is the dense grouped-query-attention tower,
+``bench/configs/dense_gqa.py``. A tower module gives:
 
-Layout (what ``repro.models.transformer.embed_pool`` reads): ``embed``
-(vocab, d) ~ N(0, 0.02²); ``dense_blocks`` stacked over layers with
-``attn.{wq,wk,wv,wo}`` and ``ffn.{w_gate,w_up,w_down}`` ~ N(0, 1/d_in) and
-RMS-norm gains ``ln1``, ``ln2`` = 1; ``final_norm`` = 1; ``embed_head``
-(d, embed_dim) ~ N(0, 1/d).
+* ``KEYS``: the sizes it reads from its group;
+* ``params_fn(t)``: ``key -> params``, traceable, in the layout the
+  program's ``repro.models.transformer.embed_pool`` reads for this
+  architecture (the module's docstring states it);
+* ``program_config(t, name)``: the program's ``TransformerConfig``;
+* ``row_flops(t, seq)``: the useful operations of one row of ``seq``
+  tokens (``harness.flops``).
+
+A tower's sizes ``t`` (:func:`tower_dict`) hold every key of its module's
+``KEYS`` and, under ``tower``, the module's name. They reach the program's
+config, the plain reference and ``serve_mfu``.
+
+Weights are drawn here, by the benchmark, on the device in one jitted call
+per tower, in the dtype the tower is served in: the program under test
+receives them as inputs, and the plain reference reads the same arrays, so
+neither side makes weights for the other.
 """
 from __future__ import annotations
+
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-TOWER_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab", "embed_dim", "rope_theta", "dtype")
+from harness import spec
+
+BENCH = Path(__file__).resolve().parents[1]
 
 
-def tower_dict(cfg: dict, which: str) -> dict:
+def tower_dict(cfg: dict, which: str, bench: Path) -> dict:
     """The tower's sizes: ``which`` is ``"expensive"`` (the file's top
-    level) or ``"cheap"`` (its ``cheap_tower`` group)."""
-    src = cfg if which == "expensive" else cfg["cheap_tower"]
-    return {k: src[k] for k in TOWER_KEYS}
+    level) or ``"cheap"`` (its ``cheap_tower`` group); its module is found
+    under ``bench``."""
+    group = spec.tower_group(cfg, which)
+    name = spec.tower_name(group)
+    mod = spec.config_module(bench, name)
+    return {**{k: group[k] for k in mod.KEYS}, "tower": name}
+
+
+def tower_module(t: dict):
+    """The tower module of sizes ``t``: the one ``t["tower"]`` names, the
+    dense one where ``t`` names none."""
+    return spec.config_module(BENCH, t.get("tower", spec.DENSE_TOWER))
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -45,48 +69,9 @@ def make_params(key: jax.Array, t: dict) -> dict:
 
 def params_fn(t: dict):
     """``key -> params`` for tower sizes ``t`` (traceable)."""
-    dtype = jnp.dtype(t["dtype"])
-    d, h, hk, hd = t["d_model"], t["n_heads"], t["n_kv_heads"], t["head_dim"]
-    f, n_l = t["d_ff"], t["n_layers"]
-
-    def normal(k, shape, std):
-        return (jax.random.normal(k, shape, dtype) * jnp.asarray(std, dtype))
-
-    def build(key):
-        ks = jax.random.split(key, 10)
-        lay = (n_l,)
-        blocks = {
-            "attn": {
-                "wq": normal(ks[0], lay + (d, h * hd), d ** -0.5),
-                "wk": normal(ks[1], lay + (d, hk * hd), d ** -0.5),
-                "wv": normal(ks[2], lay + (d, hk * hd), d ** -0.5),
-                "wo": normal(ks[3], lay + (h * hd, d), (h * hd) ** -0.5),
-            },
-            "ffn": {
-                "w_gate": normal(ks[4], lay + (d, f), d ** -0.5),
-                "w_up": normal(ks[5], lay + (d, f), d ** -0.5),
-                "w_down": normal(ks[6], lay + (f, d), f ** -0.5),
-            },
-            "ln1": jnp.ones(lay + (d,), dtype),
-            "ln2": jnp.ones(lay + (d,), dtype),
-        }
-        return {
-            "embed": normal(ks[7], (t["vocab"], d), 0.02),
-            "final_norm": jnp.ones((d,), dtype),
-            "dense_blocks": blocks,
-            "embed_head": normal(ks[8], (d, t["embed_dim"]), d ** -0.5),
-        }
-
-    return build
+    return tower_module(t).params_fn(t)
 
 
 def program_config(t: dict, name: str):
     """The program's ``TransformerConfig`` for these sizes."""
-    from repro.models.transformer import TransformerConfig
-
-    return TransformerConfig(
-        name=name, n_layers=t["n_layers"], d_model=t["d_model"],
-        n_heads=t["n_heads"], n_kv_heads=t["n_kv_heads"],
-        head_dim=t["head_dim"], d_ff=t["d_ff"], vocab=t["vocab"],
-        rope_theta=float(t["rope_theta"]), embed_dim=t["embed_dim"],
-        dtype=jnp.dtype(t["dtype"]))
+    return tower_module(t).program_config(t, name)

@@ -2,17 +2,26 @@
 
 Every configuration, traffic mix and metric is found by its name:
 ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and
-``bench/metrics/<metric>.py``. A later change adds a cell, a configuration
-or a metric by adding files and entries; no file here needs an edit.
+``bench/metrics/<metric>.py``. A configuration names its plain reference
+(``"reference"``) and, in each tower group, its tower's architecture
+(``"tower"``; the dense tower where a group names none), each a module
+``bench/configs/<module>.py``. A later change adds a cell, a
+configuration, a tower or a metric by adding files and entries; no file
+here needs an edit.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+
+DENSE_TOWER = "dense_gqa"  # the tower module of a group that names none
+TOWER_API = ("KEYS", "params_fn", "program_config", "row_flops")
+REFERENCE_API = ("embed",)
 
 
 def check_name(name: str, what: str) -> str:
@@ -20,6 +29,32 @@ def check_name(name: str, what: str) -> str:
         raise ValueError(f"{what} name {name!r} is not 1-64 of "
                          "[A-Za-z0-9_.-] starting with [A-Za-z0-9_]")
     return name
+
+
+def tower_group(cfg: dict, which: str) -> dict:
+    """The group of configuration ``cfg`` that states a tower: the file's
+    top level for ``"expensive"`` (D), its ``cheap_tower`` for ``"cheap"``
+    (d)."""
+    return cfg if which == "expensive" else cfg["cheap_tower"]
+
+
+def tower_name(group: dict) -> str:
+    """The tower module a tower group names."""
+    return group.get("tower", DENSE_TOWER)
+
+
+def config_module(bench: Path, name: str):
+    """``<bench>/configs/<name>.py``, a tower module or a plain reference,
+    executed once in a process: a later call by the same name returns that
+    module, whichever directory it names."""
+    key = f"_bench_config_{check_name(name, 'module')}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            key, Path(bench) / "configs" / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
 
 
 class Spec:
@@ -49,6 +84,9 @@ class Spec:
     def metric_path(self, name: str) -> Path:
         return self.bench / "metrics" / f"{check_name(name, 'metric')}.py"
 
+    def module_path(self, name: str) -> Path:
+        return self.bench / "configs" / f"{check_name(name, 'module')}.py"
+
     def resolve_all(self) -> None:
         """Fail on any name that does not lead to its file."""
         missing = []
@@ -62,11 +100,33 @@ class Spec:
             p = self.root / c["file"]
             if p != self.config_path(c["name"]) or not p.is_file():
                 missing.append(f"config {c['name']}: {c['file']}")
+                continue
+            missing += self.resolve_modules(c["name"],
+                                            json.loads(p.read_text()))
         for name in self.metrics:
             if not self.metric_path(name).is_file():
                 missing.append(str(self.metric_path(name)))
         if missing:
             raise FileNotFoundError("unresolved names: " + "; ".join(missing))
+
+    def resolve_modules(self, config: str, cfg: dict) -> list[str]:
+        """Load the configuration's reference and each tower group's
+        module; what is missing, or lacks a function the harness calls."""
+        wanted = [("reference", cfg.get("reference", ""), REFERENCE_API)]
+        wanted += [(f"{w} tower", tower_name(tower_group(cfg, w)), TOWER_API)
+                   for w in ("expensive", "cheap")]
+        missing = []
+        for role, name, api in wanted:
+            path = self.module_path(name)
+            if not path.is_file():
+                missing.append(f"config {config}: {role} {path}")
+                continue
+            mod = config_module(self.bench, name)
+            lacks = [a for a in api if not hasattr(mod, a)]
+            if lacks:
+                missing.append(f"config {config}: {role} {path} lacks "
+                               + ", ".join(lacks))
+        return missing
 
     def cell(self, name: str) -> dict:
         if name not in self.cells:

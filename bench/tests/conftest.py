@@ -25,6 +25,10 @@ TINY_CHEAP = dict(n_layers=1, d_model=32, n_heads=2, n_kv_heads=2,
 # about 0.05; the float32 cheap tower on the CPU reads under 1e-6, its
 # bfloat16 control about 0.005
 TINY_LIMITS = {"dist_gap": 0.02, "nn_miss": 0.5, "cheap_gap": 0.001}
+# the traffic at a test size: more distinct queries than a fast CPU serves
+# in a tiny cell's ramp and window
+TINY_MIX = {"quota": 16, "k": 5, "warmup_requests": 5, "check_sample": 8,
+            "batch": 4, "max_requests": 2000, "ramp_s": 1.0}
 
 
 @pytest.fixture(scope="session")
@@ -42,14 +46,11 @@ def tiny(spec):
         w = spec.cell(cell)
         cfg = spec.load_config(w["config"])
         mix = spec.load_traffic(w["traffic"])
-        cfg.update(TINY_EXPENSIVE, n_docs=384,
+        cfg.update(TINY_EXPENSIVE, n_docs=2048,
                    doc_len=min(cfg["doc_len"], 16),
                    query_len=min(cfg["doc_len"], 16), limits=TINY_LIMITS)
         cfg["cheap_tower"] = {**cfg["cheap_tower"], **TINY_CHEAP}
         cfg["engine"] = {**cfg["engine"], "slots": 4}
-        mix = {**mix, "quota": 16, "k": 5, "warmup_requests": 5,
-               "check_sample": 8, "batch": 4, "max_requests": 200,
-               "ramp_s": 1.0}
-        return cfg, mix
+        return cfg, {**mix, **TINY_MIX}
 
     return make

@@ -9,7 +9,7 @@ import sys
 import pytest
 from conftest import BENCH, ROOT
 
-from harness.spec import Spec, check_name
+from harness.spec import Spec, check_name, tower_group, tower_name
 
 
 def test_every_name_resolves(spec):
@@ -19,6 +19,9 @@ def test_every_name_resolves(spec):
         cfg = spec.load_config(w["config"])
         assert cfg["name"] == w["config"]
         assert (BENCH / "configs" / f"{cfg['reference']}.py").is_file()
+        for which in ("expensive", "cheap"):
+            module = tower_name(tower_group(cfg, which))
+            assert spec.module_path(module).is_file()
         for kind in ("end_to_end", "per_layer"):
             names = spec.cell_metrics(name, kind)
             assert names, (name, kind)
@@ -54,6 +57,29 @@ def test_unresolved_traffic_is_refused(tmp_path):
     doc["workloads"][0]["traffic"] = "no-such-mix"
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
     with pytest.raises(FileNotFoundError, match="no-such-mix"):
+        Spec(tmp_path)
+
+
+@pytest.mark.parametrize("group, key, module, body", [
+    (None, "tower", "no_such_tower", None),
+    ("cheap_tower", "tower", "no_such_cheap_tower", None),
+    (None, "reference", "no_such_reference", None),
+    (None, "tower", "half_tower", "KEYS = ()\n"),
+])
+def test_unresolved_module_is_refused(tmp_path, group, key, module, body):
+    """A configuration naming a tower or reference module that is not
+    there, or lacks what the harness calls, is refused as the benchmark is
+    read, before any run."""
+    doc = _copy(tmp_path)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    configs = tmp_path / "bench" / "configs"
+    if body is not None:
+        (configs / f"{module}.py").write_text(body)
+    path = configs / "scidocs-sfr7b.json"
+    cfg = json.loads(path.read_text())
+    (cfg[group] if group else cfg)[key] = module
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(FileNotFoundError, match=module):
         Spec(tmp_path)
 
 
